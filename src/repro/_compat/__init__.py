@@ -1,12 +1,6 @@
-"""Compatibility shims for optional/aging dependencies.
+"""Compatibility shim for an optional test dependency.
 
-Two concerns live here, both gated so that a fully provisioned environment
-never sees them:
-
-* ``jax_shim`` — backfills ``jax.shard_map`` (with the modern ``check_vma``
-  keyword) onto jax versions that only ship
-  ``jax.experimental.shard_map.shard_map(check_rep=...)``.
-* ``hypothesis_stub`` — a minimal property-testing stand-in installed by
-  ``tests/conftest.py`` only when the real ``hypothesis`` package is absent
-  (offline CI containers).
+``hypothesis_stub`` is a minimal property-testing stand-in installed by
+``tests/conftest.py`` only when the real ``hypothesis`` package is absent
+(offline CI containers).
 """

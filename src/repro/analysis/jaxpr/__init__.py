@@ -25,7 +25,7 @@ import dataclasses
 from typing import Callable, Iterator
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.findings import Finding
 
@@ -73,7 +73,7 @@ def _shape_dtype(var):
     return tuple(aval.shape), getattr(aval, "dtype", None)
 
 
-def trace(fn: Callable, *args, **kwargs) -> jax.core.ClosedJaxpr:
+def trace(fn: Callable, *args, **kwargs) -> jcore.ClosedJaxpr:
     return jax.make_jaxpr(fn)(*args, **kwargs)
 
 

@@ -12,19 +12,15 @@ Three modules, split by concern:
                     ``repro.core.quant`` — the paper's stochastic-rounding
                     quantizer applied to communication.
 
-Importing this package also installs the ``jax.shard_map`` compat adapter so
-the explicit expert-parallel dispatch works on older jax, and switches jax to
-*partitionable* threefry: with the legacy (non-partitionable) PRNG the random
-bits depend on the output sharding, so a mesh-sharded ``init_state`` would not
-reproduce the single-device initialization.  Partitionable threefry makes
+Importing this package switches jax to *partitionable* threefry: with the
+legacy (non-partitionable) PRNG the random bits depend on the output
+sharding, so a mesh-sharded ``init_state`` would not reproduce the
+single-device initialization.  Partitionable threefry makes
 every ``jax.random`` draw sharding-invariant — the foundation of the
 ``sharded loss == single-device loss`` contract (tests/test_distribution.py).
 """
 import jax as _jax
 
-from repro._compat.jax_shim import ensure_jax_compat as _ensure_jax_compat
-
-_ensure_jax_compat()
 _jax.config.update("jax_threefry_partitionable", True)
 
 from repro.dist import collectives, context, sharding  # noqa: E402,F401

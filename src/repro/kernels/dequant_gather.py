@@ -1,17 +1,18 @@
 """Pallas TPU kernel: fused int8-row gather + per-row de-quantize.
 
 This is the LPT forward (paper §2.3): only the rows a batch touches leave the
-integer table.  On TPU the ids are *scalar-prefetched* into SMEM so they can
-drive the BlockSpec index map — each grid step DMAs exactly one (row_block, d)
-tile of int8 codes HBM->VMEM, multiplies by the row's step size in VMEM, and
-writes the f32 rows out.  The fp table never materializes in HBM.
+integer table.  The ids are *scalar-prefetched* into SMEM so they drive the
+BlockSpec index maps: each grid step DMAs the row group that holds one id
+(``row_blocks.GROUP`` rows of int8 codes and their step sizes) HBM->VMEM,
+picks the id's row with an iota mask, multiplies by its step size, and
+merges the f32 row into an ``OUT_ROWS``-row output block that stays in VMEM
+for ``OUT_ROWS`` consecutive ids.  The fp table never materializes in HBM.
 
-Roofline: the op moves 1 byte/elem instead of 4 — it is pure memory traffic,
-so int8 codes put it 4x below the fp32 gather on the HBM roofline.
+Roofline: the op is pure memory traffic; int8 codes move 1 byte/elem where
+an fp32 gather moves 4, though each step moves its whole row group.
 
-Block shape: (1, d_block) per grid step, d_block = min(d, 512) lanes
-(multiple of 128 on real shapes); rows are independent so the grid is
-(num_ids, d_blocks) with ids prefetched.
+Grid: ``(d // d_block, b)`` with the id axis innermost, so an output block is
+complete before the next one starts.
 """
 from __future__ import annotations
 
@@ -22,21 +23,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.codestore import unpack_codes
+from repro.kernels.row_blocks import (
+    GROUP, OUT_ROWS, put_row, select_row, unpack_block,
+)
 
 
 def _kernel(ids_ref, codes_ref, step_ref, out_ref):
-    # codes_ref: (1, d_block) int8 tile of the row selected by the index map.
-    # step_ref:  (1, 1) f32 step of that row.
-    codes = codes_ref[...].astype(jnp.float32)
-    out_ref[...] = codes * step_ref[0, 0]
+    # codes_ref: (GROUP, d_block) int8 group holding the id's row.
+    # step_ref:  (GROUP, 1) f32 step sizes of that group.
+    i = pl.program_id(1)
+    r = ids_ref[i] % GROUP
+    codes = select_row(codes_ref[...], r).astype(jnp.float32)
+    row = codes * select_row(step_ref[...], r)
+    out_ref[...] = put_row(out_ref[...], i % OUT_ROWS, row)
 
 
 def _kernel_packed(ids_ref, codes_ref, step_ref, out_ref, *, bits, d):
-    # codes_ref: (1, w) packed uint8 row — the HBM->VMEM DMA moved bits/8
-    # bytes per code; the sub-byte codes only exist unpacked here in VMEM.
-    codes = unpack_codes(codes_ref[...], bits, d).astype(jnp.float32)
-    out_ref[...] = codes * step_ref[0, 0]
+    # codes_ref: (GROUP, w) packed uint8 group -- the HBM->VMEM DMA moved
+    # bits/8 bytes per code; the sub-byte codes only exist unpacked in VMEM.
+    i = pl.program_id(0)
+    r = ids_ref[i] % GROUP
+    codes = unpack_block(codes_ref[...], bits, d)
+    row = select_row(codes, r).astype(jnp.float32) * select_row(
+        step_ref[...], r
+    )
+    out_ref[...] = put_row(out_ref[...], i % OUT_ROWS, row)
+
+
+def _padded(b: int) -> int:
+    return -(-b // OUT_ROWS) * OUT_ROWS
 
 
 def dequant_gather(
@@ -55,24 +70,30 @@ def dequant_gather(
         raise ValueError(f"d={d} must be a multiple of d_block={d_block}")
     step2d = step.reshape(n, 1)
 
-    grid = (b, d // d_block)
+    grid = (d // d_block, b)
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            # One int8 row-tile per step; the prefetched ids pick the row.
-            pl.BlockSpec((1, d_block), lambda i, j, ids_ref: (ids_ref[i], j)),
-            pl.BlockSpec((1, 1), lambda i, j, ids_ref: (ids_ref[i], 0)),
+            # The row group holding ids[i]; the kernel masks out its row.
+            pl.BlockSpec(
+                (GROUP, d_block), lambda j, i, ids_ref: (ids_ref[i] // GROUP, j)
+            ),
+            pl.BlockSpec(
+                (GROUP, 1), lambda j, i, ids_ref: (ids_ref[i] // GROUP, 0)
+            ),
         ],
-        out_specs=pl.BlockSpec((1, d_block), lambda i, j, ids_ref: (i, j)),
+        out_specs=pl.BlockSpec(
+            (OUT_ROWS, d_block), lambda j, i, ids_ref: (i // OUT_ROWS, j)
+        ),
     )
     fn = pl.pallas_call(
         _kernel,
         grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((_padded(b), d), jnp.float32),
         interpret=interpret,
     )
-    return fn(ids.astype(jnp.int32), codes, step2d)
+    return fn(ids.astype(jnp.int32), codes, step2d)[:b]
 
 
 def dequant_gather_packed(
@@ -99,15 +120,15 @@ def dequant_gather_packed(
         num_scalar_prefetch=1,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, w), lambda i, ids_ref: (ids_ref[i], 0)),
-            pl.BlockSpec((1, 1), lambda i, ids_ref: (ids_ref[i], 0)),
+            pl.BlockSpec((GROUP, w), lambda i, ids_ref: (ids_ref[i] // GROUP, 0)),
+            pl.BlockSpec((GROUP, 1), lambda i, ids_ref: (ids_ref[i] // GROUP, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, ids_ref: (i, 0)),
+        out_specs=pl.BlockSpec((OUT_ROWS, d), lambda i, ids_ref: (i // OUT_ROWS, 0)),
     )
     fn = pl.pallas_call(
         functools.partial(_kernel_packed, bits=bits, d=d),
         grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((_padded(b), d), jnp.float32),
         interpret=interpret,
     )
-    return fn(ids.astype(jnp.int32), packed, step2d)
+    return fn(ids.astype(jnp.int32), packed, step2d)[:b]

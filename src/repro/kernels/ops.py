@@ -7,13 +7,15 @@ bandwidth concern, and per-tile interpretation on CPU would only add loop
 overhead without changing a single bit of the result.
 
 Alignment contract: a shape is kernel-eligible when every blocked dimension
-is a multiple of 8 (the fp32 sublane granularity; lane padding to 128 happens
-in VMEM).  Non-eligible shapes fall back to the bitwise-identical jnp
-reference in :mod:`repro.kernels.ref` — *never silently*: every fallback is
-counted and logged once per distinct (op, shape, reason), and
-:func:`fallback_stats` exposes the tally so benchmarks and trainers can
-assert the hot path actually runs fused (``EmbeddingSpec.pad_to_tiles`` is
-the knob that makes real table geometries eligible).
+is a multiple of 8 (the fp32 sublane granularity).  On TPU a block's last
+dimension is a multiple of 128 or the whole axis, and the row kernels move
+32-row groups (:mod:`repro.kernels.row_blocks`).  Non-eligible shapes fall
+back to the bitwise-identical jnp reference in :mod:`repro.kernels.ref` —
+*never silently*: every fallback is counted and logged once per distinct
+(op, shape, reason), and :func:`fallback_stats` exposes the tally so
+benchmarks and trainers can assert the hot path actually runs fused
+(``EmbeddingSpec.pad_to_tiles`` is the knob that makes real table
+geometries eligible).
 
 Dispatch accounting happens when the *wrapper* runs: eagerly per call, or
 once per trace when the call site sits inside an enclosing ``jit``.  The
@@ -63,6 +65,8 @@ logger = logging.getLogger("repro.kernels")
 
 #: fp32 sublane granularity — every blocked dimension must divide into it.
 SUBLANE = 8
+#: Lane width: a block's last dimension is a multiple of it or the whole axis.
+LANE = 128
 #: Preferred (row, col) tile targets on TPU; interpret mode uses whole arrays.
 ROW_BLOCK = 256
 COL_BLOCK = 512
@@ -221,6 +225,17 @@ def _pick_block(n: int, target: int) -> int | None:
     return None  # unreachable: SUBLANE divides n
 
 
+def _lane_block(d: int) -> int:
+    """Column block: the whole row, unless a wide row splits into
+    lane-dense (multiple-of-128) blocks of at most ``COL_BLOCK``."""
+    if _default_interpret() or d <= COL_BLOCK:
+        return d
+    for b in range(COL_BLOCK, 0, -LANE):
+        if d % b == 0:
+            return b
+    return d
+
+
 def _blocks_2d(rows: int, cols: int):
     if _default_interpret():
         # Whole-array blocks off-TPU: tiling is a VMEM concern, and per-tile
@@ -229,10 +244,9 @@ def _blocks_2d(rows: int, cols: int):
             return rows, cols
         return None
     rb = _pick_block(rows, ROW_BLOCK)
-    cb = _pick_block(cols, COL_BLOCK)
-    if rb is None or cb is None:
+    if rb is None or cols % SUBLANE:
         return None
-    return rb, cb
+    return rb, _lane_block(cols)
 
 
 # Inner jitted implementations: the public wrappers stay plain Python so the
@@ -458,13 +472,12 @@ def _dequant_gather_impl(codes, step, ids, *, use_kernel: bool = True):
     if _fault_forced("dequant_gather"):
         _note_fallback("dequant_gather", (n, d), "fault-injected")
         return _ref_dequant_gather(codes, step, ids)
-    db = d if _default_interpret() else _pick_block(d, COL_BLOCK)
-    if d % SUBLANE or db is None:
+    if d % SUBLANE:
         _note_fallback("dequant_gather", (n, d), "dim not sublane-aligned")
         return _ref_dequant_gather(codes, step, ids)
     _note_kernel("dequant_gather")
     return _dequant_gather_jit(
-        codes, step, ids, d_block=db, interpret=_default_interpret()
+        codes, step, ids, d_block=_lane_block(d), interpret=_default_interpret()
     )
 
 

@@ -18,10 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro._compat.jax_shim import ensure_pallas_interpret_params
-
-ensure_pallas_interpret_params()
-
 
 def _kernel(w_ref, step_ref, noise_ref, out_ref, *, lo: int, hi: int):
     w = w_ref[...].astype(jnp.float32)
@@ -98,16 +94,6 @@ def sr_round_seeded(
     rows, cols = w.shape
     lo, hi = -(2 ** (bits - 1)), 2 ** (bits - 1) - 1
     rb, cb = _blocks(rows, cols, row_block, col_block)
-    if getattr(type(interpret), "_compat_stub", False):
-        # TPU-semantics interpretation requested on a jax without the TPU
-        # interpreter: reproduce its documented behavior (prng_random_bits
-        # stubbed to zeros -> u == 0) with the reference formula.
-        scaled = jnp.clip(
-            w.astype(jnp.float32) / step.astype(jnp.float32)[:, None], lo, hi
-        )
-        base = jnp.floor(scaled)
-        up = (scaled - base > 0.0).astype(jnp.float32)
-        return jnp.clip(base + up, lo, hi).astype(jnp.int8)
     grid = (rows // rb, cols // cb)
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro import configs, faults, methods
 from repro.data.ctr_synth import CTRDatasetConfig, CTRSynthetic
+from repro.launch import compile_cache
 from repro.models.ctr import DCNConfig
 from repro.obs.trace import tracer
 from repro.serving.ctr import CTREngine, CTRRequest
@@ -225,6 +226,7 @@ def main(argv=None) -> int:
                        "JSON (chrome://tracing / ui.perfetto.dev) to PATH")
 
     args = ap.parse_args(argv)
+    compile_cache.setup()
     if args.fault_plan:
         plan = faults.FaultPlan.load(args.fault_plan)
         faults.install(plan)

@@ -41,6 +41,7 @@ from repro.data.lm_synth import LMTokenStream
 from repro.dist import context as dist_ctx
 from repro.dist import sharding
 from repro.kernels import ops as kernel_ops
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.obs import counters as obs_counters
 from repro.obs.stats import StreamingQuantiles
@@ -252,6 +253,7 @@ def main(argv=None) -> int:
         "(chrome://tracing / ui.perfetto.dev) to PATH at exit",
     )
     args = ap.parse_args(argv)
+    compile_cache.setup()
 
     if args.trace_out:
         tracer().enable(args.trace_out)

@@ -21,7 +21,8 @@ except ModuleNotFoundError:
 
 
 REPO_ROOT = str(pathlib.Path(__file__).resolve().parent.parent)
-SUBPROC_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}
+# JAX_PLATFORMS keeps children on the CPU: the package picks no platform.
+SUBPROC_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu"}
 
 
 def run_prog(prog: str, timeout: int = 560) -> str:

@@ -22,6 +22,7 @@ def test_sharded_train_step_matches_single_device():
         from repro import configs
         from repro.configs.common import concrete_batch
         from repro.dist import sharding, context as dist_ctx
+        from repro.launch.mesh import make_host_mesh
         from repro.training import lm_trainer
 
         cfg = configs.smoke_config("qwen3-1.7b")
@@ -36,7 +37,7 @@ def test_sharded_train_step_matches_single_device():
         s1, m1 = jax.jit(step)(s0, batch)
 
         # 4x2 mesh.
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh(data=4, model=2)
         pol = sharding.default_policy("qwen3-1.7b", multi_pod=False,
                                       model_size=2)
         st_sh = sharding.to_named(sharding.state_pspecs(cfg, pol, tcfg), mesh)
@@ -188,6 +189,7 @@ def test_seq_parallel_train_step_matches_single_device():
         from repro import configs
         from repro.configs.common import concrete_batch
         from repro.dist import sharding, context as dist_ctx
+        from repro.launch.mesh import make_host_mesh
         from repro.training import lm_trainer
 
         cfg = configs.smoke_config("qwen3-1.7b")
@@ -200,7 +202,7 @@ def test_seq_parallel_train_step_matches_single_device():
         s0 = init(jax.random.PRNGKey(0))
         s1, m1 = jax.jit(step)(s0, batch)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh(data=4, model=2)
         pol = sharding.policy_from_name("tp_sp", model_size=2, data_size=4)
         assert pol.seq_parallel
         st_sh = sharding.to_named(sharding.state_pspecs(cfg, pol, tcfg), mesh)
