@@ -743,9 +743,10 @@ class NoHostSync(Rule):
     values to the host.
 
     The obs contract (PR 10) keeps dispatch fully async when tracing is
-    off: the only sanctioned ``block_until_ready`` in ``src/repro`` is the
-    tracer's span-edge fence (a reviewed ``analysis-suppressions.txt``
-    entry — it runs only while tracing is armed, at host span boundaries).
+    off: the sanctioned ``block_until_ready`` calls in ``src/repro`` are the
+    tracer's span-edge fence (it runs only while tracing is armed, at host
+    span boundaries) and ``training/run_ahead.py``'s wait for the previous
+    step, each a reviewed ``analysis-suppressions.txt`` entry.
     Anywhere else a ``.block_until_ready()`` stalls the pipeline for every
     caller, traced or not.
 

@@ -135,15 +135,17 @@ def alpt_step(
             occ = occ[..., :d_live]
         return loss_fn_step2(occ)
 
-    g_step = fence.fence_call(jax.grad(loss_wrt_step), (step_b,), tick=tick)
-    new_step_b = step_b - cfg.step_lr * (
-        g_step + cfg.step_weight_decay * step_b
-    )
-    new_step_b = jnp.maximum(new_step_b, 1e-8)  # Delta must stay positive
-    delta_clamped = None
-    if cfg.step_clamp is not None:
-        delta_clamped = jnp.sum(new_step_b > cfg.step_clamp).astype(jnp.int32)
-        new_step_b = jnp.minimum(new_step_b, cfg.step_clamp)
+    # The Delta pass and update under one scope; the dedup above keeps its own.
+    with jax.named_scope("alpt.step_size"):
+        g_step = fence.fence_call(jax.grad(loss_wrt_step), (step_b,), tick=tick)
+        new_step_b = step_b - cfg.step_lr * (
+            g_step + cfg.step_weight_decay * step_b
+        )
+        new_step_b = jnp.maximum(new_step_b, 1e-8)  # Delta must stay positive
+        delta_clamped = None
+        if cfg.step_clamp is not None:
+            delta_clamped = jnp.sum(new_step_b > cfg.step_clamp).astype(jnp.int32)
+            new_step_b = jnp.minimum(new_step_b, cfg.step_clamp)
 
     # ---- Line 5: re-quantize w^{t+1} with the NEW Delta (SR). ----
     k2 = jax.random.fold_in(noise_key, 1)

@@ -201,13 +201,15 @@ def dedup_ids(ids: jax.Array, n_rows: int):
     """jit-stable de-duplication: returns (unique_ids [K], inverse [K_in]).
 
     ``unique_ids`` is padded with ``n_rows`` (an out-of-range sentinel row);
-    scatters use mode='drop' so padding is inert.
+    scatters use mode='drop' so padding is inert.  Its ops carry the
+    ``lpt.dedup`` scope in their metadata, where a device trace finds them.
     """
-    flat = ids.reshape(-1)
-    uniq, inv = jnp.unique(
-        flat, return_inverse=True, size=flat.shape[0], fill_value=n_rows
-    )
-    return uniq, inv.reshape(-1)
+    with jax.named_scope("lpt.dedup"):
+        flat = ids.reshape(-1)
+        uniq, inv = jnp.unique(
+            flat, return_inverse=True, size=flat.shape[0], fill_value=n_rows
+        )
+        return uniq, inv.reshape(-1)
 
 
 def sparse_apply(
@@ -252,7 +254,8 @@ def sparse_apply(
         flat_g = jnp.pad(flat_g, ((0, 0), (0, d - flat_g.shape[-1])))
     uniq, inv = dedup_ids(flat_ids, sentinel)
     k = uniq.shape[0]
-    g_sum = jnp.zeros((k, d), jnp.float32).at[inv].add(flat_g)
+    with jax.named_scope("lpt.dedup"):
+        g_sum = jnp.zeros((k, d), jnp.float32).at[inv].add(flat_g)
     count = table.count + 1
     t = count.astype(jnp.float32)
 

@@ -6,11 +6,14 @@ Four pieces, one contract:
   every existing telemetry surface re-registers into (kernel fallback
   tallies, Engine/cache counters, fault-guard and retry counters), so CLI
   reports and bench artifacts read one ``to_json()`` schema instead of four.
-* :mod:`repro.obs.trace` — host-side span tracing with Chrome-trace JSON
-  export (``--trace-out`` on both launch CLIs).  Spans wrap *host*
-  boundaries only (trainer step edge, cache write-back, Engine waves,
-  storage tier events, checkpoint save/restore); device-sync fences run
-  only at span edges and only while tracing is enabled.
+* :mod:`repro.obs.trace` — host-side span tracing.  Spans wrap *host*
+  boundaries only (trainer step with its ``train.feed``/``train.dispatch``
+  children, the wait for the previous step (``train.wait``), cache
+  write-back, Engine waves, storage tier events, checkpoint save/restore)
+  and reach any running JAX profiler trace as annotations on the device
+  trace's clock; the ALPT step's in-jit phases carry named scopes (``lpt.dedup``, ``alpt.step_size``).  Chrome-trace
+  JSON export is ``--trace-out`` on both launch CLIs; device-sync fences
+  run only at span edges and only while that export is enabled.
 * :mod:`repro.obs.stats` — streaming quantile estimation (P²) behind the
   per-host step-time quantiles and serving p50/p95/p99 that the BENCH
   artifacts carry.
@@ -19,8 +22,8 @@ Four pieces, one contract:
   ``python -m repro.analysis`` the way a jaxpr contract violation does.
 
 The hard contract: obs-on changes no jitted computation.  Spans never enter
-traced code, instrumented runs are bitwise-equal to uninstrumented
-(tests/test_obs.py), and measured overhead is asserted ≤3% in the e2e bench.
+traced code, and instrumented runs — Chrome export or profiler — are
+bitwise-equal to uninstrumented ones (tests/test_obs.py).
 """
 from __future__ import annotations
 

@@ -1,14 +1,17 @@
-"""Host-side span tracing with Chrome-trace JSON export.
+"""Host-side span tracing: into a running JAX profiler trace, and to
+Chrome-trace JSON.
 
 Spans wrap **host** boundaries only — they never enter traced code, so an
 instrumented run executes the exact same jitted programs as an
 uninstrumented one (the bitwise-parity contract in tests/test_obs.py).  The
-trainer's fused step is one jitted function by design (splitting it would
-change the compiled program); its in-jit phases — lookup/grad/sync/update —
-are therefore indivisible from the host, and the span catalog instruments
-the real host seams around them:
+span catalog:
 
-    train.step        one fused trainer step (fenced at the span edge)
+    train.step        the host's work on one fused trainer step (fenced at
+                      the span edge when the Chrome export is on), parent of:
+      train.feed        the batch's host-to-device copies
+      train.dispatch    the jitted step's call (returns once enqueued)
+    train.wait        the wait for the previous step, which bounds the
+                      host's run-ahead to one step
     train.writeback   host cache-policy write-back after the step
     train.refresh     host-refresh phase (methods that rebuild host state)
     ckpt.save         checkpoint write (atomic rename included)
@@ -24,14 +27,27 @@ the real host seams around them:
 
 plus per-request async spans (``request/<rid>``) from submit to finish.
 
-Device-sync fences run **only at span edges and only while tracing is
-enabled** (:meth:`Tracer.fence`): with tracing off the fence is a no-op and
-dispatch stays fully async.  The fence call is the repo's single reviewed
-exception to the ``no-host-sync`` lint rule (analysis-suppressions.txt).
+**Two sinks, one span.**  Whenever a JAX profiler session is recording
+(``jax.profiler.trace`` / ``start_trace``, e.g. a benchmark's traced run),
+every span is also a ``jax.profiler.TraceAnnotation``: it lands on the host
+plane of the profile, on the same clock as the device's ops.  The fused
+step's in-jit phases are not host-separable; they carry ``jax.named_scope``
+names in their op metadata instead (``lpt.dedup``: id de-duplication and
+the gradient segment-sum; ``alpt.step_size``: ALPT's step-size gradient
+pass and update).  ``enable(path)`` arms the second sink, the Chrome-trace
+JSON that ``--trace-out`` writes on both launch CLIs.
 
-Disabled-path cost: ``tracer().span(...)`` returns a shared null context
-manager — no allocation, no clock read.  Overhead of the *enabled* path is
-measured and asserted ≤3% in benchmarks/e2e_step_bench.py.
+Device-sync fences run **only at span edges and only while the Chrome
+export is enabled** (:meth:`Tracer.fence`): profiler spans never fence, so
+a profiled run keeps the dispatch pattern of an unprofiled one.  The fence
+call is the repo's single reviewed exception to the ``no-host-sync`` lint
+rule (analysis-suppressions.txt).
+
+Disabled-path cost: with neither sink on, ``tracer().span(...)`` returns a
+shared null context manager — no allocation, no clock read, no device
+read — after one C++ check of the profiler's state.  Callers keep span
+arguments host-side for the same reason: an argument is evaluated before
+``span()`` can decline it.
 
 Export is the Chrome trace-event JSON format: load the file in
 ``chrome://tracing`` or https://ui.perfetto.dev.
@@ -44,13 +60,22 @@ import os
 import time
 from typing import Any
 
+from jax._src.lib import _profiler  # private; pinned by tests/test_obs.py
+from jax.profiler import TraceAnnotation
+
 _NULL_CM = contextlib.nullcontext()
 
 
-class _Span:
-    """Context manager for one complete ('X') trace event."""
+def profiling() -> bool:
+    """Whether a JAX profiler session is recording (one C++ call)."""
+    return _profiler.TraceMe.is_enabled()
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+
+class _Span:
+    """Context manager for one complete ('X') trace event, also annotated
+    into the profiler's trace while one records."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict) -> None:
         self._tracer = tracer
@@ -58,12 +83,17 @@ class _Span:
         self._args = args
 
     def __enter__(self) -> "_Span":
+        self._annotation = TraceAnnotation(self._name, **self._args) if profiling() else None
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         self._tracer._depth += 1
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         tr = self._tracer
         tr._depth -= 1
         tr._events.append({
@@ -81,9 +111,10 @@ class _Span:
 class Tracer:
     """Span collector; a process-global instance lives behind :func:`tracer`.
 
-    Disabled by default.  ``enable(path)`` arms it and records the export
-    path; ``export()`` writes the Chrome-trace JSON (called by the launch
-    CLIs at end of run, or explicitly).
+    Disabled by default; its spans still reach a recording profiler.
+    ``enable(path)`` arms the Chrome-trace collector and records the export
+    path; ``export()`` writes the JSON (called by the launch CLIs at end of
+    run, or explicitly).
     """
 
     def __init__(self) -> None:
@@ -116,10 +147,14 @@ class Tracer:
 
     def span(self, name: str, **args: Any):
         """Context manager timing one host-side phase (nesting = call nesting
-        in the exported trace).  Near-zero cost while disabled."""
-        if not self.enabled:
-            return _NULL_CM
-        return _Span(self, name, args)
+        in the exported trace and in the profiler's).  A profiler-only span
+        is a bare ``TraceAnnotation``; with neither sink on, the shared null
+        context."""
+        if self.enabled:
+            return _Span(self, name, args)
+        if profiling():
+            return TraceAnnotation(name, **args)
+        return _NULL_CM
 
     def instant(self, name: str, **args: Any) -> None:
         """Zero-duration marker ('i') — fault injections, straggler flags."""
@@ -162,9 +197,11 @@ class Tracer:
     def fence(self, value: Any) -> Any:
         """Device-sync fence at a span *edge*.
 
-        While tracing, block until ``value``'s device work is done so the
-        enclosing span measures compute, not dispatch.  While disabled this
-        is a pure pass-through — no sync, dispatch stays async.  This is the
+        While the Chrome export is enabled, block until ``value``'s device
+        work is done so the enclosing span measures compute, not dispatch.
+        Otherwise — a profiler recording included, whose device plane
+        already times the compute — a pure pass-through: no sync, dispatch
+        stays async.  This is the
         one reviewed ``no-host-sync`` exception (analysis-suppressions.txt):
         it is host code at a span boundary, never inside a step function.
         """

@@ -26,6 +26,7 @@ from repro.models import embedding as emb_mod
 from repro.obs.trace import tracer
 from repro.optim import adam_init, adam_update
 from repro.storage.tiered import HotRowCache
+from repro.training.run_ahead import RunAhead
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,6 +89,7 @@ class CTRTrainer:
                 )
         self.guard_stats = faults.GuardStats() if cfg.guard else None
         self._train_step = self._build_train_step()
+        self._run_ahead = RunAhead()
         self._eval_logits = jax.jit(self._logits_fn)
 
     # ------------------------------------------------------------ init
@@ -396,18 +398,24 @@ class CTRTrainer:
 
     def train_step(self, state: TrainState, ids: np.ndarray, labels: np.ndarray):
         # Span edges sit at the host boundaries only: the fused step is ONE
-        # jitted function by design (its lookup/grad/update phases are not
-        # host-separable), so the span fences its edge and the write-back
-        # phase gets its own span.  With tracing off both spans are shared
-        # null context managers and the fence is a no-op — the jitted
-        # computation is identical either way (tests/test_obs.py holds the
-        # instrumented run bitwise-equal).
+        # jitted function by design (its in-jit phases carry named scopes
+        # instead), so ``train.step`` holds the host's work on the step —
+        # the batch's copies and the dispatch — and the wait for the
+        # previous step and the write-back phase get spans of their own.
+        # Nothing here reads the device: with tracing off the spans are
+        # shared null context managers, the fence is a no-op, and the host
+        # runs one step ahead of the device — the jitted computation is
+        # identical either way (tests/test_obs.py holds the instrumented
+        # run bitwise-equal).
         tr = tracer()
-        with tr.span("train.step", step=int(state.step)):
-            state, m = self._train_step(
-                state, jnp.asarray(ids), jnp.asarray(labels)
-            )
+        with tr.span("train.step"):
+            with tr.span("train.feed"):
+                ids_d, labels_d = jnp.asarray(ids), jnp.asarray(labels)
+            with tr.span("train.dispatch"):
+                state, m = self._train_step(state, ids_d, labels_d)
             tr.fence(m)
+        with tr.span("train.wait"):
+            self._run_ahead.dispatched(m)
         if self.guard_stats is not None:
             self.guard_stats.observe(m)
         with tr.span("train.writeback"):

@@ -17,9 +17,13 @@
 * **Legacy schemas** — ``ops.fallback_stats()`` and
   ``EngineMetrics.to_json()`` keep their pre-registry keys bit-for-bit.
 """
+import glob
 import json
+import os
+import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -278,17 +282,164 @@ def _train_losses_and_state(method, steps=4):
     return losses, [np.asarray(x).tobytes() for x in exported]
 
 
-@pytest.mark.parametrize("method", ["lpt", "alpt"])
-def test_instrumented_training_bitwise_equal(method):
+@pytest.mark.parametrize("method,sink", [
+    pytest.param("lpt", "chrome", id="lpt"),
+    pytest.param("alpt", "chrome", id="alpt"),
+    pytest.param("alpt", "profiler", id="alpt-profiler"),
+])
+def test_instrumented_training_bitwise_equal(method, sink, tmp_path):
     base_losses, base_state = _train_losses_and_state(method)
-    tracer().enable()
-    try:
-        inst_losses, inst_state = _train_losses_and_state(method)
-    finally:
-        tracer().disable()
-        tracer().clear()
+    if sink == "chrome":
+        tracer().enable()
+        try:
+            inst_losses, inst_state = _train_losses_and_state(method)
+        finally:
+            tracer().disable()
+            tracer().clear()
+    else:
+        with jax.profiler.trace(str(tmp_path)):
+            inst_losses, inst_state = _train_losses_and_state(method)
     assert inst_losses == base_losses
     assert inst_state == base_state
+
+
+# ------------------------------------- profiler spans, scopes, no syncs
+
+
+def _profile_planes(trace_dir):
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)[0]
+    return ProfileData.from_file(path).planes
+
+
+def _host_spans(trace_dir) -> dict:
+    """{name: [(start_ns, end_ns)]} of the host planes' events."""
+    out = {}
+    for plane in _profile_planes(trace_dir):
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append((e.start_ns, e.end_ns))
+    return out
+
+
+def test_profiler_state_check_is_pinned(tmp_path):
+    """repro.obs.trace asks a private jaxlib call whether a profiler records;
+    this pins that call's meaning across jax upgrades."""
+    from jax._src.lib import _profiler
+
+    from repro.obs.trace import profiling
+
+    assert _profiler.TraceMe.is_enabled() is False and not profiling()
+    with jax.profiler.trace(str(tmp_path)):
+        assert _profiler.TraceMe.is_enabled() is True and profiling()
+    assert not profiling()
+
+
+def test_span_is_a_profiler_annotation_that_never_fences(tmp_path, monkeypatch):
+    t = Tracer()
+    fenced = []
+    monkeypatch.setattr(jax, "block_until_ready", lambda v: fenced.append(v) or v)
+    with jax.profiler.trace(str(tmp_path)):
+        span = t.span("train.refresh", step=3)
+        assert isinstance(span, jax.profiler.TraceAnnotation)
+        with span:
+            t.fence(np.ones(2))
+    assert fenced == [] and t.events == []
+    assert t.span("a") is t.span("b")  # the shared null context again
+    assert "train.refresh" in _host_spans(str(tmp_path))
+
+
+def test_enabled_span_also_reaches_the_profiler(tmp_path):
+    t = Tracer()
+    t.enable()
+    with jax.profiler.trace(str(tmp_path)):
+        with t.span("ckpt.save", step=1):
+            pass
+    assert [e["name"] for e in t.events] == ["ckpt.save"]
+    assert "ckpt.save" in _host_spans(str(tmp_path))
+
+
+def test_trainer_spans_reach_the_profiler(tmp_path):
+    data = CTRSynthetic(OBS_DATA)
+    tr = _trainer("alpt")
+    state, _ = tr.train_step(tr.init_state(), *data.batch("train", 0, 32))
+    with jax.profiler.trace(str(tmp_path)):
+        state, m = tr.train_step(state, *data.batch("train", 1, 32))
+        jax.block_until_ready(m)
+    spans = _host_spans(str(tmp_path))
+    for name in ("train.step", "train.feed", "train.dispatch", "train.wait",
+                 "train.writeback"):
+        assert len(spans.get(name, [])) == 1, (name, sorted(spans))
+    (s0, s1), = spans["train.step"]
+    for child in ("train.feed", "train.dispatch"):
+        (c0, c1), = spans[child]
+        assert s0 <= c0 <= c1 <= s1
+    assert spans["train.feed"][0][1] <= spans["train.dispatch"][0][0]
+    # The wait for the previous step is host idling, not the step's host
+    # work: it follows train.step instead of nesting in it.
+    assert s1 <= spans["train.wait"][0][0] <= spans["train.writeback"][0][0]
+
+
+def test_host_runs_at_most_one_step_ahead(monkeypatch):
+    """Each step waits for the one before it and never for itself, so at
+    most two steps hold device buffers."""
+    from repro.training import run_ahead
+
+    data = CTRSynthetic(OBS_DATA)
+    tr = _trainer("alpt")
+    state = tr.init_state()
+    waited, dispatched = [], []
+    monkeypatch.setattr(run_ahead.jax, "block_until_ready",
+                        lambda v: waited.append(v) or v)
+    for i in range(3):
+        state, m = tr.train_step(state, *data.batch("train", i, 32))
+        dispatched.append(m)
+    assert [id(w) for w in waited] == [id(m) for m in dispatched[:2]]
+
+
+@pytest.mark.parametrize("method", ["lpt", "alpt"])
+def test_train_step_reads_nothing_from_the_device(method, monkeypatch):
+    """With the tracer off a step only copies its batch in and dispatches:
+    no device-to-host read, so the host can run ahead of the chip."""
+    from jax._src import array as jarray
+
+    data = CTRSynthetic(OBS_DATA)
+    tr = _trainer(method)
+    state, _ = tr.train_step(tr.init_state(), *data.batch("train", 0, 32))
+    jax.block_until_ready(state)
+    value = jarray.ArrayImpl.__dict__["_value"]
+    reads = []
+
+    def counting(self):
+        reads.append(self.shape)
+        return value.fget(self)
+
+    monkeypatch.setattr(jarray.ArrayImpl, "_value", property(counting))
+    for i in (1, 2):
+        state, m = tr.train_step(state, *data.batch("train", i, 32))
+    monkeypatch.undo()
+    assert reads == []
+    assert np.isfinite(float(m["loss"]))
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_fused_step_carries_phase_scopes(bits):
+    """The ALPT step's in-jit phases are named in the compiled step's op
+    metadata (int8 and packed 4-bit), and no op is in both."""
+    data = CTRSynthetic(OBS_DATA)
+    tr = _trainer("alpt", bits=bits)
+    ids, labels = data.batch("train", 0, 32)
+    hlo = tr._train_step.lower(tr.init_state(), jnp.asarray(ids),
+                               jnp.asarray(labels)).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    dedup = {n for n in names if "/lpt.dedup/" in n}
+    step_size = {n for n in names if "/alpt.step_size/" in n}
+    assert any("sort" in n for n in dedup)  # jnp.unique's sort
+    assert any("scatter" in n for n in dedup)  # the gradient segment-sum
+    assert any("while" in n for n in step_size)  # the fenced Delta pass
+    assert not dedup & step_size
 
 
 def _engine_probs():
