@@ -62,28 +62,6 @@ def run():
          f"ref_us={us_ref:.1f} bytes={fused_b} "
          f"unfused_bytes={unfused_b} traffic_saved={unfused_b/fused_b:.1f}x")
 
-    # Fused CTR sparse step over unique rows (gather+Adam+SR+scatter).
-    # Table scaled down vs the gather bench: the interpreter walks the grid
-    # row by row, and the derived bytes column is size-linear anyway.
-    ns, kk, dd = 20_000, 512, 128
-    mu = jax.random.normal(next(keys), (ns, dd)) * 0.01
-    nu = jax.random.uniform(next(keys), (ns, dd)) * 1e-3
-    codes_k = jax.random.randint(next(keys), (ns, dd), -128, 128, jnp.int8)
-    step_k = jax.random.uniform(next(keys), (ns,), minval=1e-3, maxval=0.1)
-    uniq = jax.random.permutation(next(keys), ns)[:kk].astype(jnp.int32)
-    g_rows = jax.random.normal(next(keys), (kk, dd)) * 0.1
-    nz = jax.random.uniform(next(keys), (kk, dd))
-    args = (codes_k, step_k, mu, nu, uniq, g_rows, nz,
-            jnp.float32(0.01), jnp.float32(0.1), jnp.float32(1e-3), 8)
-    us = _time(lambda *a: ops.sparse_row_update(*a), *args)
-    us_ref = _time(
-        lambda *a: ops.sparse_row_update(*a, use_kernel=False), *args
-    )
-    row_b = kk * dd * (1 + 4 + 4 + 4 + 4 + 1 + 4 + 4 + 4)
-    emit("kernel/sparse_row_update", us,
-         f"ref_us={us_ref:.1f} touched_row_bytes={row_b} "
-         f"rows={kk} fp32_table_never_in_hbm=1")
-
     x = jax.random.normal(next(keys), (256, 2048), jnp.bfloat16)
     wc = jax.random.randint(next(keys), (2048, 2048), -128, 128, jnp.int8)
     ws = jax.random.uniform(next(keys), (2048,), minval=1e-3, maxval=0.02)

@@ -36,8 +36,9 @@ class ALPTConfig(NamedTuple):
     step_lr: float = 2e-5  # paper: Delta learning rate 2e-5
     step_weight_decay: float = 5e-8  # paper: same decay as embeddings (8-bit)
     grad_scale: str = "bdq"  # '1' | 'dq' | 'bdq'  (Fig. 4 sweep)
-    # Route the lookup / write-back hot loops through repro.kernels.ops
-    # (methods copy EmbeddingSpec.use_kernels in here; bitwise-identical).
+    # Route the lookups, the dense write-back and the line-5 requantize
+    # through repro.kernels.ops (methods copy EmbeddingSpec.use_kernels in
+    # here; bitwise-identical).  The sparse write-back takes no such switch.
     use_kernels: bool = False
     # Absolute upper bound on the learned Delta (guardrail against step-size
     # blowup: one huge Delta poisons the whole row's quantization grid).
@@ -115,7 +116,6 @@ def alpt_step(
         weight_decay=cfg.weight_decay,
         return_updated_rows=True,
         id_space=id_space,
-        use_kernels=cfg.use_kernels,
     )
     # ---- Step 2: learn Delta on the *updated* float rows (line 4). ----
     # Re-run the forward with fake-quantized updated rows; the LSQ custom-vjp
@@ -158,8 +158,13 @@ def alpt_step(
         codes_rows = quant.quantize_codes(
             w_new, new_step_b, cfg.bits, cfg.rounding, noise
         )
-    codes = rowstore.set_rows(table1.codes, uniq, codes_rows, mode="drop")
-    step = table1.step.at[uniq].set(new_step_b, mode="drop")
+    # ``uniq`` is sorted; its dropped slots come last (lpt.sparse_apply).
+    codes = rowstore.set_rows(
+        table1.codes, uniq, codes_rows, mode="drop", indices_are_sorted=True
+    )
+    step = table1.step.at[uniq].set(
+        new_step_b, mode="drop", indices_are_sorted=True
+    )
     new_table = table1._replace(codes=codes, step=step)
     aux = {
         "step_grad_norm": jnp.linalg.norm(g_step),

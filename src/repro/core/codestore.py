@@ -208,7 +208,8 @@ class CodeStore:
     # ------------------------------------------------------------ writes
 
     def set_rows(self, rows_idx: jax.Array, codes_rows: jax.Array, *,
-                 mode: str = "drop") -> "CodeStore":
+                 mode: str = "drop",
+                 indices_are_sorted: bool = False) -> "CodeStore":
         """Functional row scatter: int8 ``[k, d]`` rows -> new CodeStore.
 
         Packs the incoming rows first when the container is packed, so the
@@ -219,7 +220,9 @@ class CodeStore:
             rows = pack_codes(codes_rows, self.bits)
         else:
             rows = codes_rows.astype(self.data.dtype)
-        return self.with_data(self.data.at[rows_idx].set(rows, mode=mode))
+        return self.with_data(self.data.at[rows_idx].set(
+            rows, mode=mode, indices_are_sorted=indices_are_sorted
+        ))
 
     def where_rows(self, row_mask: jax.Array,
                    codes_new: "CodeStore | jax.Array") -> "CodeStore":

@@ -226,22 +226,19 @@ def sparse_apply(
     new_step: jax.Array | None = None,  # ALPT passes the freshly learned Delta_b
     return_updated_rows: bool = False,
     id_space: int | None = None,  # sentinel for dedup (< n_rows on padded tables)
-    use_kernels: bool = False,
 ):
     """Paper-faithful LPT update: only rows present in ``ids`` change.
 
     Duplicate ids in the batch have their gradients summed (the same semantics
-    autodiff would give a dense table scatter-add).
+    autodiff would give a dense table scatter-add).  The write-back is one
+    batched row gather, the row update over ``[K, d]`` and one in-place row
+    scatter per leaf, for every table layout and row optimizer; its ops
+    carry the ``lpt.row_update`` scope.
 
-    ``id_space`` is the logical id range (``spec.n``); on ``pad_to_tiles``
-    tables it is smaller than ``n_rows``, which turns the dedup sentinel into
-    a real-but-dead *scratch row* — the precondition for the fused
-    ``ops.sparse_row_update`` kernel, whose ids-driven aliased scatter must
-    never point outside the table.  ``use_kernels`` routes the
-    gather+Adam+SR+scatter loop through that kernel when eligible (SR
-    rounding, row-Adam, no ALPT ``new_step``, scratch row present); anything
-    else falls back to the jnp path below, which is bitwise-compatible on
-    every live row (scratch-row bytes are unspecified scratch on both paths).
+    ``id_space`` is the logical id range (``spec.n``, smaller than
+    ``n_rows`` on ``pad_to_tiles`` tables).  The dedup slots that hold no
+    batch id (ids at or past ``id_space``) point past the table, so all of
+    their writes drop; ``return_updated_rows`` returns those row indices.
     """
     n = table.n_rows
     d = table.dim
@@ -259,78 +256,41 @@ def sparse_apply(
     count = table.count + 1
     t = count.astype(jnp.float32)
 
-    kernel_ok = False
-    if use_kernels:
-        # Eligibility gate for the fused kernel; an ineligible kernels-on
-        # dispatch is a counted fallback, never a silent one.
-        if isinstance(table.codes, TieredCodes):
-            # The fused kernel's aliased scatter writes the backing container
-            # directly; cached rows must route through the hot tier instead.
-            ops.note_fallback(
-                "sparse_row_update", (n, d), "tiered hot-row cache"
-            )
-        elif rounding != "sr":
-            ops.note_fallback("sparse_row_update", (n, d), "dr rounding")
-        elif optimizer != "adam":
-            ops.note_fallback(
-                "sparse_row_update", (n, d), f"row optimizer {optimizer!r}"
-            )
-        elif new_step is not None:
-            ops.note_fallback(
-                "sparse_row_update", (n, d), "caller-supplied new_step"
-            )
-        elif sentinel >= n:  # no scratch row for the aliased scatter
-            ops.note_fallback(
-                "sparse_row_update", (n, d),
-                "no scratch row past the id space (pad_to_tiles off)",
-            )
-        else:
-            kernel_ok = True
-    if kernel_ok:
-        if noise_key is None:
-            raise ValueError("SR requires noise_key")
-        noise = quant.sr_noise(noise_key, (k, d))
-        c1 = 1.0 - 0.9**t
-        c2 = 1.0 - 0.999**t
-        codes2, mu2, nu2, w_new = ops.sparse_row_update(
-            table.codes, table.step, table.mu, table.nu, uniq, g_sum, noise,
-            lr, c1, c2, bits, weight_decay=weight_decay,
+    if rounding == "sr" and noise_key is None:
+        raise ValueError("SR requires noise_key")
+    # Gather -> Adam + requantize -> scatter over the K deduplicated rows.
+    # ``rows`` is sorted with the dropped slots last, so every scatter may
+    # say so; the dropped slots repeat, so the indices are not unique.
+    with jax.named_scope("lpt.row_update"):
+        rows = jnp.where(uniq < sentinel, uniq, n)
+        safe = jnp.minimum(rows, n - 1)
+        step_rows = jnp.take(table.step, safe)
+        w = quant.dequantize(rowstore.take_rows(table.codes, safe), step_rows)
+        # Slot layout is optimizer-dependent ([k, d] adam / [k] otherwise)
+        # but the gather is row-indexed either way.
+        mu = jnp.take(table.mu, safe, axis=0)
+        nu = jnp.take(table.nu, safe, axis=0)
+        w_new, mu_new, nu_new = _row_update(
+            w, g_sum, mu, nu, t, lr, optimizer, weight_decay
         )
-        new_table = LPTTable(
-            codes=codes2, step=table.step, mu=mu2, nu=nu2, count=count
+        if new_step is not None:
+            step_rows = new_step
+        noise = (
+            quant.sr_noise(noise_key, w_new.shape) if rounding == "sr" else None
         )
-        if return_updated_rows:
-            return new_table, (uniq, w_new)
-        return new_table
-
-    # Gather current rows + optimizer slots (sentinel gathers row 0 harmlessly;
-    # its scatter is dropped).
-    safe = jnp.minimum(uniq, n - 1)
-    w = quant.dequantize(
-        rowstore.take_rows(table.codes, safe), jnp.take(table.step, safe)
-    )
-    # Slot layout is optimizer-dependent ([k, d] adam / [k] otherwise) but the
-    # gather is row-indexed either way.
-    mu = jnp.take(table.mu, safe, axis=0)
-    nu = jnp.take(table.nu, safe, axis=0)
-    w_new, mu_new, nu_new = _row_update(
-        w, g_sum, mu, nu, t, lr, optimizer, weight_decay
-    )
-    step_rows = jnp.take(table.step, safe) if new_step is None else new_step
-    if rounding == "sr":
-        if noise_key is None:
-            raise ValueError("SR requires noise_key")
-        noise = quant.sr_noise(noise_key, w_new.shape)
-    else:
-        noise = None
-    new_codes_rows = quant.quantize_codes(w_new, step_rows, bits, rounding, noise)
-    codes = rowstore.set_rows(table.codes, uniq, new_codes_rows, mode="drop")
-    step = table.step.at[uniq].set(step_rows, mode="drop")
-    mu_t = table.mu.at[uniq].set(mu_new, mode="drop")
-    nu_t = table.nu.at[uniq].set(nu_new, mode="drop")
+        new_codes_rows = quant.quantize_codes(
+            w_new, step_rows, bits, rounding, noise
+        )
+        scatter = dict(mode="drop", indices_are_sorted=True)
+        codes = rowstore.set_rows(table.codes, rows, new_codes_rows, **scatter)
+        step = table.step
+        if new_step is not None:
+            step = step.at[rows].set(new_step, **scatter)
+        mu_t = table.mu.at[rows].set(mu_new, **scatter)
+        nu_t = table.nu.at[rows].set(nu_new, **scatter)
     new_table = LPTTable(codes=codes, step=step, mu=mu_t, nu=nu_t, count=count)
     if return_updated_rows:
-        return new_table, (uniq, w_new)
+        return new_table, (rows, w_new)
     return new_table
 
 

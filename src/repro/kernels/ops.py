@@ -54,10 +54,6 @@ from repro.kernels.lpt_update import lpt_fused_update as _lpt_fused_update
 from repro.kernels.lpt_update import (
     lpt_fused_update_packed as _lpt_fused_update_packed,
 )
-from repro.kernels.sparse_row_update import sparse_row_update as _sparse_row_update
-from repro.kernels.sparse_row_update import (
-    sparse_row_update_packed as _sparse_row_update_packed,
-)
 from repro.kernels.sr_round import sr_round as _sr_round
 from repro.kernels.sr_round import sr_round_seeded as sr_round_seeded  # re-export
 
@@ -144,9 +140,9 @@ def _note_fallback(op: str, shape, reason: str) -> None:
 
 def note_fallback(op: str, shape, reason: str) -> None:
     """Public hook for callers that bypass a kernel *before* reaching its
-    wrapper (e.g. lpt.sparse_apply's eligibility gate: no scratch row, non-
-    Adam row optimizer, DR rounding).  Keeps the 'never silent' contract:
-    every kernels-on dispatch that lands on the jnp path is counted."""
+    wrapper (e.g. lpt.dense_apply on DR rounding or a tiered hot-row
+    cache).  Keeps the 'never silent' contract: every kernels-on dispatch
+    that lands on the jnp path is counted."""
     _note_fallback(op, shape, reason)
 
 
@@ -337,48 +333,6 @@ def _ref_lpt_update_packed_jit(packed, step, grad, noise, lr, new_step, *,
     return ref.lpt_fused_update_packed_ref(
         packed, step, grad, noise, lr, bits, d,
         new_step=new_step if has_new_step else None,
-        weight_decay=weight_decay,
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bits", "weight_decay", "interpret")
-)
-def _sparse_row_update_jit(codes, step, mu, nu, uniq, g_sum, noise, lr, c1,
-                           c2, bits, *, weight_decay, interpret):
-    return _sparse_row_update(
-        codes, step, mu, nu, uniq, g_sum, noise, lr, c1, c2, bits,
-        weight_decay=weight_decay, interpret=interpret,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("bits", "weight_decay"))
-def _ref_sparse_row_update_jit(codes, step, mu, nu, uniq, g_sum, noise, lr,
-                               c1, c2, bits, *, weight_decay):
-    return ref.sparse_row_update_ref(
-        codes, step, mu, nu, uniq, g_sum, noise, lr, c1, c2, bits,
-        weight_decay=weight_decay,
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bits", "d", "weight_decay", "interpret")
-)
-def _sparse_row_update_packed_jit(packed, step, mu, nu, uniq, g_sum, noise,
-                                  lr, c1, c2, *, bits, d, weight_decay,
-                                  interpret):
-    return _sparse_row_update_packed(
-        packed, step, mu, nu, uniq, g_sum, noise, lr, c1, c2, bits, d,
-        weight_decay=weight_decay, interpret=interpret,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("bits", "d", "weight_decay"))
-def _ref_sparse_row_update_packed_jit(packed, step, mu, nu, uniq, g_sum,
-                                      noise, lr, c1, c2, *, bits, d,
-                                      weight_decay):
-    return ref.sparse_row_update_packed_ref(
-        packed, step, mu, nu, uniq, g_sum, noise, lr, c1, c2, bits, d,
         weight_decay=weight_decay,
     )
 
@@ -582,89 +536,6 @@ def lpt_update(codes, step, grad, noise, lr, bits: int, *, new_step=None,
         codes, step, grad, noise, lr, ns, bits,
         weight_decay=weight_decay, row_block=blocks[0], col_block=blocks[1],
         interpret=_default_interpret(), has_new_step=has_new_step,
-    )
-
-
-def sparse_row_update(codes, step, mu, nu, uniq, g_sum, noise, lr, c1, c2,
-                      bits: int, *, weight_decay: float = 0.0,
-                      use_kernel: bool = True):
-    """Fused CTR sparse step over unique rows (gather+Adam+SR+scatter).
-
-    ``uniq`` must contain only in-range ids — the caller maps jnp.unique's
-    sentinel padding to the table's scratch row (``pad_to_tiles`` allocates
-    it).  Adam slots must be [N, d] (row-Adam); other row optimizers use the
-    jnp path upstream.  Returns ``(codes', mu', nu', w_new_rows)``.
-
-    A :class:`CodeStore` input returns a CodeStore ``codes'`` with the same
-    layout; a packed store keeps the aliased scatter on packed bytes
-    (re-packed in VMEM before the write-back).
-    """
-    if isinstance(codes, CodeStore) and codes.packed:
-        store = codes
-        n, d = store.shape
-        if not use_kernel:
-            out, mu2, nu2, w_new = _ref_sparse_row_update_packed_jit(
-                store.data, step, mu, nu, uniq, g_sum, noise, lr, c1, c2,
-                bits=bits, d=d, weight_decay=weight_decay,
-            )
-            return store.with_data(out), mu2, nu2, w_new
-        if _fault_forced("sparse_row_update"):
-            _note_fallback("sparse_row_update", (n, d), "fault-injected")
-            out, mu2, nu2, w_new = _ref_sparse_row_update_packed_jit(
-                store.data, step, mu, nu, uniq, g_sum, noise, lr, c1, c2,
-                bits=bits, d=d, weight_decay=weight_decay,
-            )
-            return store.with_data(out), mu2, nu2, w_new
-        if d % SUBLANE or d > COL_BLOCK:
-            _note_fallback(
-                "sparse_row_update", (n, d),
-                "dim not sublane-aligned" if d % SUBLANE
-                else "dim exceeds one block",
-            )
-            out, mu2, nu2, w_new = _ref_sparse_row_update_packed_jit(
-                store.data, step, mu, nu, uniq, g_sum, noise, lr, c1, c2,
-                bits=bits, d=d, weight_decay=weight_decay,
-            )
-            return store.with_data(out), mu2, nu2, w_new
-        _note_kernel("sparse_row_update")
-        out, mu2, nu2, w_new = _sparse_row_update_packed_jit(
-            store.data, step, mu, nu, uniq, g_sum, noise, lr, c1, c2,
-            bits=bits, d=d, weight_decay=weight_decay,
-            interpret=_default_interpret(),
-        )
-        return store.with_data(out), mu2, nu2, w_new
-    if isinstance(codes, CodeStore):
-        store = codes
-        out, mu2, nu2, w_new = sparse_row_update(
-            store.data, step, mu, nu, uniq, g_sum, noise, lr, c1, c2, bits,
-            weight_decay=weight_decay, use_kernel=use_kernel,
-        )
-        return store.with_data(out), mu2, nu2, w_new
-    n, d = codes.shape
-    if not use_kernel:
-        return _ref_sparse_row_update_jit(
-            codes, step, mu, nu, uniq, g_sum, noise, lr, c1, c2, bits,
-            weight_decay=weight_decay,
-        )
-    if _fault_forced("sparse_row_update"):
-        _note_fallback("sparse_row_update", (n, d), "fault-injected")
-        return _ref_sparse_row_update_jit(
-            codes, step, mu, nu, uniq, g_sum, noise, lr, c1, c2, bits,
-            weight_decay=weight_decay,
-        )
-    if d % SUBLANE or d > COL_BLOCK:
-        _note_fallback(
-            "sparse_row_update", (n, d),
-            "dim not sublane-aligned" if d % SUBLANE else "dim exceeds one block",
-        )
-        return _ref_sparse_row_update_jit(
-            codes, step, mu, nu, uniq, g_sum, noise, lr, c1, c2, bits,
-            weight_decay=weight_decay,
-        )
-    _note_kernel("sparse_row_update")
-    return _sparse_row_update_jit(
-        codes, step, mu, nu, uniq, g_sum, noise, lr, c1, c2, bits,
-        weight_decay=weight_decay, interpret=_default_interpret(),
     )
 
 

@@ -47,17 +47,6 @@ def lpt_fused_update_packed_ref(packed, step, grad, noise, lr, bits: int,
     return pack_codes(codes_new, bits)
 
 
-def sparse_row_update_packed_ref(packed, step, mu, nu, uniq, g_sum, noise,
-                                 lr, c1, c2, bits: int, d: int, *,
-                                 weight_decay: float = 0.0, b1: float = 0.9,
-                                 b2: float = 0.999, eps: float = 1e-8):
-    codes, mu_new, nu_new, w_new = sparse_row_update_ref(
-        unpack_codes(packed, bits, d), step, mu, nu, uniq, g_sum, noise,
-        lr, c1, c2, bits, weight_decay=weight_decay, b1=b1, b2=b2, eps=eps,
-    )
-    return pack_codes(codes, bits), mu_new, nu_new, w_new
-
-
 def sr_round_ref(w: jax.Array, step: jax.Array, noise: jax.Array, bits: int) -> jax.Array:
     lo, hi = -(2 ** (bits - 1)), 2 ** (bits - 1) - 1
     scaled = jnp.clip(w.astype(jnp.float32) / step[:, None], lo, hi)
@@ -99,47 +88,3 @@ def lpt_fused_update_ref(
     base = jnp.floor(scaled)
     up = (scaled - base > noise).astype(jnp.float32)
     return jnp.clip(base + up, lo, hi).astype(jnp.int8)
-
-
-def sparse_row_update_ref(
-    codes: jax.Array,  # int8 [N, d]
-    step: jax.Array,  # f32 [N]
-    mu: jax.Array,  # f32 [N, d] Adam first moment
-    nu: jax.Array,  # f32 [N, d] Adam second moment
-    uniq: jax.Array,  # int32 [K] unique row ids (all < N)
-    g_sum: jax.Array,  # f32 [K, d] summed per-row gradients
-    noise: jax.Array,  # f32 [K, d] uniform [0,1)
-    lr, c1, c2,  # f32 scalars: learning rate, 1-b1^t, 1-b2^t
-    bits: int,
-    *,
-    weight_decay: float = 0.0,
-    b1: float = 0.9,
-    b2: float = 0.999,
-    eps: float = 1e-8,
-):
-    """Oracle for the fused CTR sparse step: gather + Adam + SR + scatter.
-
-    Returns ``(codes', mu', nu', w_new_rows)``.  ``uniq`` must hold distinct
-    in-range ids (the wrapper maps jnp.unique's sentinel padding to the
-    table's scratch row before calling either path).
-    """
-    lo, hi = -(2 ** (bits - 1)), 2 ** (bits - 1) - 1
-    w = jnp.take(codes, uniq, axis=0).astype(jnp.float32) * jnp.take(step, uniq)[:, None]
-    g = g_sum.astype(jnp.float32)
-    mu_r = b1 * jnp.take(mu, uniq, axis=0) + (1.0 - b1) * g
-    nu_r = b2 * jnp.take(nu, uniq, axis=0) + (1.0 - b2) * jnp.square(g)
-    upd = (mu_r / c1) / (jnp.sqrt(nu_r / c2) + eps)
-    if weight_decay:
-        upd = upd + weight_decay * w
-    w_new = w - lr * upd
-    step_rows = jnp.take(step, uniq)[:, None]
-    scaled = jnp.clip(w_new / step_rows, lo, hi)
-    base = jnp.floor(scaled)
-    up = (scaled - base > noise).astype(jnp.float32)
-    codes_rows = jnp.clip(base + up, lo, hi).astype(jnp.int8)
-    return (
-        codes.at[uniq].set(codes_rows),
-        mu.at[uniq].set(mu_r),
-        nu.at[uniq].set(nu_r),
-        w_new,
-    )

@@ -1,16 +1,16 @@
-"""Row access inside TPU-tiled blocks, shared by the row kernels.
+"""Row access inside TPU-tiled blocks, for the row-gather kernel.
 
 Mosaic refuses a ``(1, d)`` block, a ``(1, 1)`` block and a dynamic row
 index into an int8 block: a block's last two dimensions must be multiples of
 the dtype's tile (8 sublanes for f32, 32 for int8/uint8) or span the array.
-So the row kernels move one *row group* per grid step -- ``GROUP`` table
-rows, the int8 sublane tile, which also covers the f32 tile -- and pick or
-replace the wanted row with an iota mask.  The mask ops are exact: a select
+So the row-gather kernel moves one *row group* per grid step -- ``GROUP``
+table rows, the int8 sublane tile, which also covers the f32 tile -- picks
+the wanted row with an iota mask and places it in its output block with
+another.  The mask ops are exact: a select
 keeps every bit, and a masked integer sum adds zeros to one value.
 
-Packed sub-byte containers are spread to one code per lane, and gathered
-back into bytes, with a 0/1 matrix on the MXU.  Each output is one byte
-value (at most 255) or a sum of disjoint bit fields below 256, so the
+Packed sub-byte containers are spread to one code per lane with a 0/1
+matrix on the MXU.  Each output is one byte value (at most 255), so the
 products and sums are exact at any matmul precision.
 """
 from __future__ import annotations
@@ -71,18 +71,3 @@ def unpack_block(packed: jax.Array, bits: int, d: int) -> jax.Array:
     half = 1 << (bits - 1)
     return jnp.where(u >= half, u - (1 << bits), u)
 
-
-def pack_block(codes: jax.Array, bits: int, w: int) -> jax.Array:
-    """int32 ``(g, d)`` signed codes -> uint8 ``(g, w)`` container block
-    (the layout of :func:`repro.core.codestore.pack_codes`)."""
-    cpb = 8 // bits
-    d = codes.shape[-1]
-    shift = (jax.lax.broadcasted_iota(jnp.int32, (1, d), 1) % cpb) * bits
-    fields = jax.lax.shift_left(codes & ((1 << bits) - 1), shift)
-    packed = jnp.dot(
-        fields.astype(jnp.float32),
-        _spread_matrix(w, d, cpb).T,
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )
-    return packed.astype(jnp.int32).astype(jnp.uint8)

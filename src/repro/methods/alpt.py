@@ -3,9 +3,9 @@
 Inherits the LPT table/state handling; overrides the train-step pieces with
 the two-substep schedule (weight update, then Delta learned via a second
 fake-quant forward at the *updated* dense params).  ``spec.use_kernels``
-flows into :class:`~repro.core.alpt.ALPTConfig` so both sub-steps run fused:
-the weight step through ``ops.sparse_row_update``/``ops.lpt_update`` and the
-line-5 requantize-with-learned-Delta through ``ops.sr_round``.
+flows into :class:`~repro.core.alpt.ALPTConfig` so the lookups, the dense
+weight step (``ops.lpt_update``) and the line-5 requantize-with-learned-Delta
+(``ops.sr_round``) run fused; the sparse weight step is XLA's row path.
 
 The learned Delta is exactly what serving keeps: ``serving_state`` (inherited
 int8-resident export) ships codes + the *learned* per-row scales straight

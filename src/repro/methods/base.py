@@ -77,17 +77,18 @@ class EmbeddingSpec:
     row_optimizer: str = "adam"
     hash_compression: float = 2.0
     prune: pruning_core.PruneConfig = pruning_core.PruneConfig()
-    # Route integer-table hot paths (lookup / write-back / sparse step)
-    # through the Pallas kernel suite (repro.kernels.ops).  Default on; the
+    # Route integer-table lookups and dense write-backs through the Pallas
+    # kernel suite (repro.kernels.ops); the sparse step is XLA's row path
+    # either way.  Default on; the
     # wrappers auto-interpret off-TPU and fall back — counted, never silently
     # — on kernel-ineligible shapes.
     use_kernels: bool = True
     # Pad the table geometry up to kernel tiles at init: rows round up to the
-    # sublane multiple *past* the id space (the extra row is the scratch row
-    # the fused sparse scatter parks dedup sentinels in), dim rounds up to
-    # the sublane multiple.  Lookups/dense tables are sliced back to (n, d),
-    # so padding is invisible to the model — it exists so real geometries hit
-    # the kernel path instead of the shape fallback.
+    # sublane multiple *past* the id space (one scratch row that nothing
+    # writes), dim rounds up to the sublane multiple.  Lookups/dense tables
+    # are sliced back to (n, d), so padding is invisible to the model — it
+    # exists so real geometries hit the kernel path instead of the shape
+    # fallback.
     pad_to_tiles: bool = False
     # Code-container layout (repro.core.codestore): True packs sub-byte code
     # widths (bits in {2, 4}) into uint8 at 8//bits codes per byte; False

@@ -1,11 +1,12 @@
 """LPT: int8 codes + per-row Delta, no fp32 master copy (paper §2.3, Eq. 8).
 
 Thin adapter over :mod:`repro.core.lpt` — the paper-faithful math stays there.
-``spec.use_kernels`` routes every hot path through the fused Pallas kernels
-(``repro.kernels.ops``): lookups via ``dequant_gather``, the CTR sparse step
-via ``sparse_row_update``, the dense write-back via ``lpt_update``;
-``spec.pad_to_tiles`` allocates the table at kernel-tile geometry (live
-``(n, d)`` is sliced back out everywhere the model looks).
+``spec.use_kernels`` routes the lookups (``dequant_gather``) and the dense
+write-back (``lpt_update``) through the fused Pallas kernels
+(``repro.kernels.ops``); the CTR sparse step is XLA's row gather, update and
+scatter (``lpt_core.sparse_apply``) either way.  ``spec.pad_to_tiles``
+allocates the table at kernel-tile geometry (live ``(n, d)`` is sliced back
+out everywhere the model looks).
 
 Serving ships the table as-is: ``serving_state`` (inherited from
 :class:`~repro.methods.base.IntegerTableMethod`) hands the codes + per-row
@@ -71,7 +72,6 @@ class LPTMethod(IntegerTableMethod):
             lr=lr, bits=spec.bits, rounding=spec.alpt.rounding,
             noise_key=noise_key, optimizer=spec.row_optimizer,
             weight_decay=weight_decay, id_space=spec.n,
-            use_kernels=spec.use_kernels,
         )
 
     def dense_update(self, state, opt, grads, *, spec, lr, weight_decay,

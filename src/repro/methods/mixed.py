@@ -205,8 +205,7 @@ class MixedMethod(IntegerTableMethod):
         for g, sub in enumerate(state.subs):
             rows_g = plan.group_rows[g]
             # Non-member occurrences map to the dedup sentinel: they collapse
-            # into one unique entry whose scatter lands on the scratch row
-            # (padded tables) or drops (mode='drop'), never on live rows.
+            # into one unique entry whose writes drop, never on live rows.
             sub_ids = jnp.where(gid == g, local, rows_g)
             subs.append(
                 lpt_core.sparse_apply(
@@ -216,7 +215,6 @@ class MixedMethod(IntegerTableMethod):
                     noise_key=jax.random.fold_in(noise_key, g),
                     optimizer=spec.row_optimizer,
                     weight_decay=weight_decay, id_space=rows_g,
-                    use_kernels=spec.use_kernels,
                 )
             )
         return MixedTable(subs=tuple(subs))

@@ -10,10 +10,10 @@ rule: d(rem * quo)/drem = quo and vice versa.
 This file is the registry's existence proof: a brand-new method wired into
 both trainers, the DP wrapper, serving, sharding, and checkpointing without
 touching any of them — everything below is registered state + formulations.
-The kernel path composes for free: each sub-table routes its lookups and row
-updates through the same ``repro.kernels.ops`` hot paths as plain LPT
-(``spec.use_kernels``), each with its own dedup sentinel / scratch row under
-``spec.pad_to_tiles``.
+The kernel path composes for free: each sub-table routes its lookups through
+the same ``repro.kernels.ops`` hot paths as plain LPT (``spec.use_kernels``)
+and its row updates through the same ``sparse_apply``, each with its own
+dedup sentinel / scratch row under ``spec.pad_to_tiles``.
 """
 from __future__ import annotations
 
@@ -101,7 +101,6 @@ class QRLPTMethod(IntegerTableMethod):
             lr=lr, bits=spec.bits, rounding=spec.alpt.rounding,
             noise_key=key, optimizer=spec.row_optimizer,
             weight_decay=weight_decay, id_space=id_space,
-            use_kernels=spec.use_kernels,
         )
 
     def sparse_apply(self, state, ids, g_rows, *, spec, lr, weight_decay,
@@ -283,7 +282,7 @@ class QRALPTMethod(QRLPTMethod):
         k_quo = jax.random.fold_in(noise_key, 1)
         kw = dict(lr=lr, bits=spec.bits, rounding=spec.alpt.rounding,
                   optimizer=spec.row_optimizer, weight_decay=weight_decay,
-                  return_updated_rows=True, use_kernels=spec.use_kernels)
+                  return_updated_rows=True)
         rem1, (uniq_r, w_new_r) = lpt_core.sparse_apply(
             state.remainder, rid, g_rows * quo, noise_key=k_rem, id_space=r,
             **kw,

@@ -64,7 +64,8 @@ class RowStore(Protocol):
     def take(self, ids: jax.Array) -> jax.Array: ...
 
     def set_rows(self, rows_idx: jax.Array, codes_rows: jax.Array, *,
-                 mode: str = "drop") -> "RowStore": ...
+                 mode: str = "drop",
+                 indices_are_sorted: bool = False) -> "RowStore": ...
 
     def where_rows(self, row_mask: jax.Array,
                    codes_new: "RowStore | jax.Array") -> "RowStore": ...
@@ -96,11 +97,16 @@ def take_rows(codes, ids: jax.Array) -> jax.Array:
 
 
 def set_rows(codes, rows_idx: jax.Array, codes_rows: jax.Array, *,
-             mode: str = "drop"):
-    """Functional row scatter of int8 ``[k, d]`` rows -> new container."""
+             mode: str = "drop", indices_are_sorted: bool = False):
+    """Functional row scatter of int8 ``[k, d]`` rows -> new container.
+
+    ``indices_are_sorted`` promises ``rows_idx`` non-decreasing.
+    """
     if is_row_store(codes):
-        return codes.set_rows(rows_idx, codes_rows, mode=mode)
-    return codes.at[rows_idx].set(codes_rows, mode=mode)
+        return codes.set_rows(rows_idx, codes_rows, mode=mode,
+                              indices_are_sorted=indices_are_sorted)
+    return codes.at[rows_idx].set(codes_rows, mode=mode,
+                                  indices_are_sorted=indices_are_sorted)
 
 
 def where_rows(codes, row_mask: jax.Array, codes_new):

@@ -135,11 +135,14 @@ class TieredCodes:
     # ------------------------------------------------------------ writes
 
     def set_rows(self, rows_idx: jax.Array, codes_rows: jax.Array, *,
-                 mode: str = "drop") -> "TieredCodes":
+                 mode: str = "drop",
+                 indices_are_sorted: bool = False) -> "TieredCodes":
         """Row scatter routed per id: cached rows write the hot tier only
         (the host manager marks them dirty); uncached rows write the backing.
         Out-of-range ids (dedup sentinels) behave exactly as the backing
         would: real scratch rows are written, true OOB indices drop.
+        The routing breaks any order of ``rows_idx``, so
+        ``indices_are_sorted`` is not passed on.
         """
         n = self.shape[0]
         slot = self.slots_for(rows_idx)

@@ -257,105 +257,6 @@ def test_lpt_fused_update_matches_ref(bits, shape, rb, cb):
     assert (diff != 0).mean() < 1e-4
 
 
-# ------------------------------------------------------- sparse_row_update
-
-
-@pytest.mark.parametrize("bits", [2, 4, 8])
-@pytest.mark.parametrize("weight_decay", [0.0, 5e-8])
-def test_sparse_row_update_matches_ref_bitwise(bits, weight_decay):
-    """Fused gather+Adam+SR+scatter == the jnp oracle, bit for bit."""
-    key = jax.random.PRNGKey(30)
-    ks = jax.random.split(key, 6)
-    n, d, k = 48, 16, 24
-    codes = jax.random.randint(ks[0], (n, d), -(2**(bits-1)), 2**(bits-1), jnp.int8)
-    step = jax.random.uniform(ks[1], (n,), minval=1e-3, maxval=0.05)
-    mu = jax.random.normal(ks[2], (n, d)) * 0.01
-    nu = jax.random.uniform(ks[3], (n, d)) * 1e-3
-    uniq = jnp.asarray(
-        np.random.RandomState(5).choice(n, k, replace=False), jnp.int32
-    )
-    g = jax.random.normal(ks[4], (k, d)) * 0.1
-    noise = jax.random.uniform(ks[5], (k, d))
-    t = 7.0
-    c1, c2 = 1.0 - 0.9**t, 1.0 - 0.999**t
-    on = ops.sparse_row_update(
-        codes, step, mu, nu, uniq, g, noise, 0.01, c1, c2, bits,
-        weight_decay=weight_decay, use_kernel=True,
-    )
-    off = ops.sparse_row_update(
-        codes, step, mu, nu, uniq, g, noise, 0.01, c1, c2, bits,
-        weight_decay=weight_decay, use_kernel=False,
-    )
-    # The table state (codes + Adam slots) is the bitwise contract.
-    for a, b in zip(on[:3], off[:3]):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # The auxiliary float rows may differ by one ULP where XLA's FMA
-    # formation lands differently across the two traces; the train-step
-    # parity suite (tests/test_methods_conformance.py) holds the end-to-end
-    # state bitwise on the shipped configs.
-    np.testing.assert_allclose(
-        np.asarray(on[3]), np.asarray(off[3]), rtol=1e-6, atol=1e-9
-    )
-
-
-def test_sparse_row_update_untouched_rows_bit_identical():
-    """The aliased scatter leaves rows outside ``uniq`` byte-for-byte alone."""
-    key = jax.random.PRNGKey(31)
-    ks = jax.random.split(key, 6)
-    n, d, k = 32, 8, 4
-    codes = jax.random.randint(ks[0], (n, d), -128, 128, jnp.int8)
-    step = jax.random.uniform(ks[1], (n,), minval=1e-3, maxval=0.05)
-    mu = jax.random.normal(ks[2], (n, d)) * 0.01
-    nu = jax.random.uniform(ks[3], (n, d)) * 1e-3
-    uniq = jnp.array([3, 9, 17, 31], jnp.int32)
-    g = jax.random.normal(ks[4], (k, d)) * 0.1
-    noise = jax.random.uniform(ks[5], (k, d))
-    out_codes, out_mu, out_nu, _ = ops.sparse_row_update(
-        codes, step, mu, nu, uniq, g, noise, 0.01, 0.1, 0.001, 8,
-    )
-    untouched = np.setdiff1d(np.arange(n), np.asarray(uniq))
-    np.testing.assert_array_equal(
-        np.asarray(out_codes)[untouched], np.asarray(codes)[untouched]
-    )
-    np.testing.assert_array_equal(
-        np.asarray(out_mu)[untouched], np.asarray(mu)[untouched]
-    )
-    np.testing.assert_array_equal(
-        np.asarray(out_nu)[untouched], np.asarray(nu)[untouched]
-    )
-    touched = np.asarray(uniq)
-    assert (np.asarray(out_mu)[touched] != np.asarray(mu)[touched]).any()
-
-
-def test_sparse_row_update_equals_core_sparse_apply():
-    """Kernel path == lpt.sparse_apply's jnp path on every live row (the
-    dedup sentinel parks in the scratch row, excluded)."""
-    from repro.core import lpt as lpt_core
-
-    key = jax.random.PRNGKey(32)
-    k1, k2, k3 = jax.random.split(key, 3)
-    n_live, d = 19, 16
-    n_alloc = 24  # allocated past the id space: row 19 is the scratch row
-    table = lpt_core.init_table(k1, n_alloc, d, 8, optimizer="adam")
-    ids = jnp.array([[0, 5, 5], [18, 2, 5]], jnp.int32)
-    g_rows = jax.random.normal(k2, ids.shape + (d,)) * 0.1
-    kw = dict(lr=jnp.float32(0.01), bits=8, rounding="sr", noise_key=k3,
-              optimizer="adam", weight_decay=5e-8, id_space=n_live)
-    on = lpt_core.sparse_apply(table, ids, g_rows, use_kernels=True, **kw)
-    off = lpt_core.sparse_apply(table, ids, g_rows, use_kernels=False, **kw)
-    live = np.arange(n_live)
-    np.testing.assert_array_equal(
-        np.asarray(on.codes)[live], np.asarray(off.codes)[live]
-    )
-    np.testing.assert_array_equal(
-        np.asarray(on.mu)[live], np.asarray(off.mu)[live]
-    )
-    np.testing.assert_array_equal(
-        np.asarray(on.nu)[live], np.asarray(off.nu)[live]
-    )
-    np.testing.assert_array_equal(np.asarray(on.count), np.asarray(off.count))
-
-
 def test_lpt_fused_update_with_new_step_matches_core():
     """Fused kernel == the unfused core path (dequant -> sgd -> SR requant),
     including ALPT's Delta' requantize (Algorithm 1 line 5)."""
@@ -380,7 +281,7 @@ def test_lpt_fused_update_with_new_step_matches_core():
 #
 # The packed-storage contract: a CodeStore at bits in {2, 4} keeps its codes
 # packed through every fused op — packed bytes move HBM->VMEM, the unpack
-# (and the scatter's re-pack) happen in VMEM — and the results are BITWISE
+# (and the dense write-back's re-pack) happen in VMEM — and the results are BITWISE
 # equal to the raw int8 path, kernels on or off.
 
 
@@ -424,38 +325,6 @@ def test_packed_lpt_update_bitwise(bits, use_kernel):
     np.testing.assert_array_equal(
         np.asarray(got.unpack()), np.asarray(expect)
     )
-
-
-@pytest.mark.parametrize("bits", [2, 4])
-@pytest.mark.parametrize("use_kernel", [True, False])
-def test_packed_sparse_row_update_bitwise(bits, use_kernel):
-    raw, store, step = _packed_fixture(bits)
-    n, d = raw.shape
-    k = 8
-    ks = jax.random.split(jax.random.PRNGKey(42), 4)
-    mu = jax.random.normal(ks[0], (n, d)) * 0.01
-    nu = jax.random.uniform(ks[1], (n, d)) * 1e-3
-    uniq = jnp.asarray(
-        np.random.RandomState(6).choice(n, k, replace=False), jnp.int32
-    )
-    g = jax.random.normal(ks[2], (k, d)) * 0.1
-    noise = jax.random.uniform(ks[3], (k, d))
-    t = 3.0
-    c1, c2 = 1.0 - 0.9**t, 1.0 - 0.999**t
-    got = ops.sparse_row_update(
-        store, step, mu, nu, uniq, g, noise, 0.01, c1, c2, bits,
-        use_kernel=use_kernel,
-    )
-    expect = ops.sparse_row_update(
-        raw, step, mu, nu, uniq, g, noise, 0.01, c1, c2, bits,
-        use_kernel=False,
-    )
-    assert got[0].bits == bits and got[0].packed
-    np.testing.assert_array_equal(
-        np.asarray(got[0].unpack()), np.asarray(expect[0])
-    )
-    for a, b in zip(got[1:3], expect[1:3]):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("bits", [2, 4])

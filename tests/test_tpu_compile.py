@@ -11,12 +11,14 @@ test worker collects the same tests and only the worker running this file
 loads the TPU compiler.
 """
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import lpt
 from repro.core.codestore import CodeStore, packed_width
 from repro.kernels import ops
 
@@ -63,13 +65,16 @@ def compile_for_chip(one_chip, monkeypatch):
     the dispatch tally."""
     monkeypatch.setattr(ops, "_default_interpret", lambda: False)
 
-    def run(fn, *shapes):
+    def run(fn, *shapes, lowered=None):
         placed = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
             shapes,
         )
         with _no_persistent_cache(), ops.fallback_scope() as scope:
-            text = jax.jit(fn).lower(*placed).compile().as_text()
+            low = jax.jit(fn).lower(*placed)
+            text = low.compile().as_text()
+        if lowered is not None:  # the program as traced, before XLA's passes
+            lowered.append(low.as_text())
         return text, scope.stats()
 
     return run
@@ -102,19 +107,41 @@ def test_dequant_gather_compiles_for_v5e(compile_for_chip, bits):
 
 
 @pytest.mark.parametrize("bits", [8, 4])
-def test_sparse_row_update_compiles_for_v5e(compile_for_chip, bits):
-    def update(codes, step, mu, nu, uniq, g, noise, lr, c1, c2):
-        return ops.sparse_row_update(
-            codes, step, mu, nu, uniq, g, noise, lr, c1, c2, bits,
-            weight_decay=1e-5,
+def test_sparse_apply_compiles_for_v5e(compile_for_chip, bits):
+    """The CTR row write-back (gather -> row Adam + SR -> scatter) for a
+    B-id batch: its table scatters carry the K = B dedup slots, and neither
+    Adam moment is relaid out or copied more than once (the copy that the
+    un-donated state forces before the in-place scatter)."""
+    def update(table, ids, g, lr, key):
+        return lpt.sparse_apply(
+            table, ids, g, lr=lr, bits=bits, noise_key=key,
+            weight_decay=1e-5, id_space=N - 2,
         )
 
-    text, stats = compile_for_chip(
-        update, _codes(bits), _f32(N), _f32(N, D), _f32(N, D),
-        jax.ShapeDtypeStruct((B,), jnp.int32), _f32(B, D), _f32(B, D),
-        _f32(), _f32(), _f32(),
+    table = lpt.LPTTable(
+        codes=_codes(bits), step=_f32(N), mu=_f32(N, D), nu=_f32(N, D),
+        count=jax.ShapeDtypeStruct((), jnp.int32),
     )
-    _assert_kernel("sparse_row_update", text, stats)
+    lowered = []
+    text, stats = compile_for_chip(
+        update, table, jax.ShapeDtypeStruct((B,), jnp.int32), _f32(B, D),
+        _f32(), jax.ShapeDtypeStruct((2,), jnp.uint32), lowered=lowered,
+    )
+    assert "tpu_custom_call" not in text
+    assert stats["kernel_calls"] == {} and stats["total_fallbacks"] == 0
+    # Scatters into table-shaped operands (leading dim N): codes, mu, nu.
+    sig = re.findall(
+        r"\}\) : \(tensor<(\d+)x[^,]*, tensor<\d+x1xi32>, tensor<(\d+)x",
+        lowered[0],
+    )
+    table_scatters = [k for n, k in sig if int(n) == N]
+    assert table_scatters == [str(B)] * 3, sig
+    entry = text[text.index("ENTRY"):]
+    moment = re.compile(rf"= \(?f32\[{N},{D}\]\{{([\d,]+):")
+    layouts = {m.group(1) for m in moment.finditer(entry)}
+    assert len(layouts) == 1, layouts  # the parameters' layout throughout
+    copies = re.findall(rf"= f32\[{N},{D}\]\S* copy\(", entry)
+    assert len(copies) <= 2, copies
 
 
 def test_sr_round_compiles_for_v5e(compile_for_chip):
