@@ -12,9 +12,14 @@ ready.
 With ``"chips": 4`` in the cell the step is the program's data-parallel step
 over a 4-device ``data`` mesh (``sync_bits`` from the traffic), the batch
 sharded over it; ``batch`` is then per chip.
+
+``check_program`` and ``rehearsal`` serve the benchmark's tests: the first
+raises where the program differs from a configuration file, the second cuts
+a cell to a size the CPU runs (``tests/rehearsal/<config>.json``).
 """
 from __future__ import annotations
 
+import copy
 import time
 
 import jax
@@ -27,6 +32,29 @@ import reference
 import zipf
 
 NOISE_SALT = 0xBE4C
+
+
+def check_program(cfg: dict) -> None:
+    """Raises where the program built from ``cfg`` differs from the file."""
+    program.trainer_config(cfg)
+
+
+def rehearsal(cfg: dict, traffic: dict, limits: dict, cut: dict):
+    """(config, traffic, limits) cut to a CPU size by the numbers in ``cut``:
+    the id total scaled by ``id_scale`` (and with it the field cardinalities
+    and the program's table), widths and depths as they are, the small batch
+    and pool, and the limits that hold at that batch.  A data-parallel cell
+    (its traffic has ``sync_bits``) takes ``cut["data_parallel"]``'s batch
+    per chip and limits.  ``limits``, the real cell's, are replaced."""
+    cfg = copy.deepcopy(cfg)
+    scale, data = cut["id_scale"], cfg["data"]
+    cfg["program"]["scale"] = scale
+    data["id_total"] = int(data["id_total"] * scale)
+    data["n_ids"] = sum(zipf.field_cards(data["fields"], data["id_total"], data["card_seed"]))
+    part = cut["data_parallel"] if "sync_bits" in traffic else cut
+    traffic = dict(traffic, batch=part["batch"], pool_batches=cut["pool_batches"],
+                   check_steps=cut["check_steps"])
+    return cfg, traffic, dict(part["limits"])
 
 
 def make_pool(cfg: dict, traffic: dict, seed: int, chips: int):

@@ -7,6 +7,8 @@ sits in a file of its own, found by name:
 * ``configs/<config>.json``: sizes, source, cuts and assumptions;
 * ``traffic/<traffic>.json``: the mix's parameters; its ``kind`` names the
   runner in ``cells/<kind>.py``;
+* ``cells/<kind>.py``: the runner, with ``run(run)``, ``check_program(config)``
+  and ``rehearsal(config, traffic, limits, cut)`` (``tests/conftest.py``);
 * ``layer_metrics/<metric>.py``: a ``read(run)`` that returns the metric or
   None when it finds nothing to read;
 * ``limits/<workload>.json``: the limit of each number that decides
@@ -213,10 +215,12 @@ def read_layer_metrics(run: Run) -> dict:
 
     if run.trace_dir is None:
         return {}
+    t0 = time.monotonic()
     try:
         run.reduced = trace_reduce.reduce_dir(run.trace_dir)
     finally:
         shutil.rmtree(run.trace_dir, ignore_errors=True)
+    run.counts["trace_read_s"] = time.monotonic() - t0
     out = {}
     for m in run.cell.per_layer:
         value = load_module(run.cell.bench / "layer_metrics" / f"{m['name']}.py").read(run)
@@ -245,10 +249,11 @@ def result_line(run: Run, checks: dict, correct: bool) -> dict:
     return out
 
 
-def report(run: Run, checks: dict, correct: bool) -> None:
-    line = result_line(run, checks, correct)
+def report(run: Run, line: dict) -> None:
+    """The result line last on standard output, each compared number beside
+    its limit last on standard error."""
     print(json.dumps({"counts": run.counts}, default=str), file=sys.stderr)
-    for k, v in checks.items():
+    for k, v in line["checks"].items():
         print(f"check {k}: {v['value']!r} limit {v['limit']!r}", file=sys.stderr)
-    print(f"check correct: {correct}", file=sys.stderr, flush=True)
+    print(f"check correct: {line['correct']}", file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)

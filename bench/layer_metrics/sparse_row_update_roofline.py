@@ -1,13 +1,15 @@
-"""The row write-back kernel's share of its roofline.
+"""The row write-back's share of its roofline.
 
 Per unique id of each batch (counted on the host): read its code row
 (d * bits / 8 bytes), step (4), Adam moments (2 * 4d), summed gradient (4d)
 and id (4); write its code row, moments and the updated f32 row (4d).  The
 rounding noise is not counted: a kernel can draw it on chip.  Bound by HBM
-bandwidth.  Time: the summed device time of the ``sparse_row_update`` ops in
-the window."""
+bandwidth.  These are the least bytes the work needs, whatever does it.
+Time: the device time under the program's ``lpt.row_update`` scope in the
+window (its row gathers, the row Adam + SR requantize and its scatters),
+mean over chips."""
 
-KERNEL = r"sparse_row_update"
+SCOPE = "lpt.row_update"
 
 
 def bytes_per_unique(cfg: dict) -> float:
@@ -20,7 +22,7 @@ def read(run):
     red = run.reduced
     if red is None:
         return None
-    seconds, _ = red.ops_matching(KERNEL)
+    seconds = red.scope_seconds(SCOPE)
     if seconds <= 0:
         return None
     need = run.counts["unique_ids"] * bytes_per_unique(run.config)

@@ -41,7 +41,7 @@ def main(argv=None) -> int:
                       trace=bool(args.trace), t_start=T_START, devices=devices,
                       peaks=harness.peaks_for(devices[0].device_kind))
     checks, correct = cell.runner().run(run)
-    harness.report(run, checks, correct)
+    harness.report(run, harness.result_line(run, checks, correct))
     return 0
 
 

@@ -15,62 +15,82 @@ for p in (str(BENCH), str(ROOT / "src")):
 
 import json  # noqa: E402
 import shutil  # noqa: E402
+import typing  # noqa: E402
 
 import pytest  # noqa: E402
 
-#: Tiny cells for rehearsals on the CPU: the real configurations with their
-#: id totals cut (and so their field cardinalities) and small batches.
-#: Widths and model depths are the real ones.
-TINY_SCALE = {"criteo-dcn-alpt8": 0.002, "avazu-dcn-alpt4": 0.0005}
-TINY_TRAFFIC = {
-    "tiny-train": {"kind": "train", "batch": 16, "pool_batches": 4, "check_steps": 3},
-    "tiny-dp": {"kind": "train", "batch": 8, "pool_batches": 4, "check_steps": 3,
-                "sync_bits": 8},
-}
-#: Limits for the tiny cells: at batch 16 the stochastic rounding of a
-#: few hundred rows moves the later losses and the change far more than at
-#: the real sizes.
-TINY_LIMITS = {
-    "train": {"loss_gap": 0.02, "grad_gap": 1e-3, "change_gap": 0.08},
-    # The compressed sync rounds each replica's gradient stochastically; at
-    # batch 8 a chip that noise moves the gradient norms by about 3e-3.
-    "dp": {"loss_gap": 0.02, "grad_gap": 0.02, "change_gap": 0.08},
-}
-TINY_CELLS = [
-    ("tiny-criteo-train", "criteo-dcn-alpt8", "tiny-train", 1),
-    ("tiny-avazu-train", "avazu-dcn-alpt4", "tiny-train", 1),
-    ("tiny-criteo-dp", "criteo-dcn-alpt8", "tiny-dp", 4),
-]
+REHEARSAL = pathlib.Path("bench", "tests", "rehearsal")
 
 
-def make_tiny_root(dest: pathlib.Path) -> pathlib.Path:
-    """A checkout-like directory: ``bench/`` copied, tiny cells added as files."""
-    import zipf
+class TinyCell(typing.NamedTuple):
+    """A rehearsal of a real workload: its name, the workload's entry in
+    ``BENCHMARK.json``, the chips it runs on (virtual devices on the CPU),
+    what it sets in the workload's traffic, and the rehearsal file's cut."""
 
-    shutil.copytree(BENCH, dest / "bench", ignore=shutil.ignore_patterns("__pycache__"))
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    for c in list(bench["configs"]):
-        cfg = json.loads((ROOT / c["file"]).read_text())
-        scale = TINY_SCALE[c["name"]]
-        cfg["program"]["scale"] = scale
-        cfg["data"]["id_total"] = int(cfg["data"]["id_total"] * scale)
-        cfg["data"]["n_ids"] = sum(zipf.field_cards(
-            cfg["data"]["fields"], cfg["data"]["id_total"], cfg["data"]["card_seed"]))
-        cfg["name"] = "tiny-" + c["name"]
-        (dest / "bench" / "configs" / f"{cfg['name']}.json").write_text(json.dumps(cfg))
-        bench["configs"].append({"name": cfg["name"], "source": c["source"],
-                                 "file": f"bench/configs/{cfg['name']}.json",
-                                 "reduced": ["id_total"]})
-    for name, traffic in TINY_TRAFFIC.items():
-        (dest / "bench" / "traffic" / f"{name}.json").write_text(json.dumps(traffic))
-    for name, config, traffic, chips in TINY_CELLS:
-        bench["workloads"].append({"name": name, "config": "tiny-" + config,
-                                   "traffic": traffic, "chips": chips, "why": "rehearsal"})
-        kind = "dp" if chips > 1 else "train"
-        (dest / "bench" / "limits" / f"{name}.json").write_text(json.dumps(TINY_LIMITS[kind]))
+    name: str
+    workload: dict
+    chips: int
+    traffic: dict
+    cut: dict
+
+
+def rehearsal_file(root: pathlib.Path, config: str) -> pathlib.Path:
+    """Where a configuration's rehearsal cut lives: the numbers its runner's
+    ``rehearsal`` takes, and optionally ``tiny_cells``, {workload: [{"name",
+    "chips", "traffic"}]}, the tiny cells built from each workload (by
+    default one, ``tiny-<workload>``, on the workload's chips)."""
+    return root / REHEARSAL / f"{config}.json"
+
+
+def tiny_cells(root: pathlib.Path = ROOT) -> list:
+    """The tiny cells of every workload whose configuration has a rehearsal
+    file (``make_tiny_root`` and ``test_rehearsal`` refuse one without)."""
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    out = []
+    for w in bench["workloads"]:
+        path = rehearsal_file(root, w["config"])
+        if not path.exists():
+            continue
+        cut = json.loads(path.read_text())
+        for t in cut.get("tiny_cells", {}).get(w["name"], [{"name": "tiny-" + w["name"]}]):
+            out.append(TinyCell(t["name"], w, t.get("chips", w["chips"]), t.get("traffic", {}),
+                                cut))
+    return out
+
+
+def missing_rehearsals(root: pathlib.Path = ROOT) -> list:
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    return [str(rehearsal_file(root, c["name"])) for c in bench["configs"]
+            if not rehearsal_file(root, c["name"]).exists()]
+
+
+def make_tiny_root(dest: pathlib.Path, src: pathlib.Path = ROOT) -> pathlib.Path:
+    """A checkout-like directory: ``src``'s ``bench/`` copied, and beside each
+    workload its tiny cells as files, each cut by its runner's ``rehearsal``
+    from the configuration's rehearsal file."""
+    import harness
+
+    missing = missing_rehearsals(src)
+    if missing:
+        raise FileNotFoundError(f"no rehearsal file for a configuration: {missing}")
+    shutil.copytree(src / "bench", dest / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    bench = json.loads((src / "BENCHMARK.json").read_text())
+    for tiny in tiny_cells(src):
+        real = tiny.workload["name"]
+        cell = harness.load_cell(real, src)
+        cfg, traffic, limits = cell.runner().rehearsal(
+            cell.config, dict(cell.traffic, **tiny.traffic), cell.limits, tiny.cut)
+        cfg["name"] = tiny.name
+        for kind, data in (("configs", cfg), ("traffic", traffic), ("limits", limits)):
+            (dest / "bench" / kind / f"{tiny.name}.json").write_text(json.dumps(data))
+        bench["configs"].append({"name": tiny.name, "source": f"rehearsal of {real}",
+                                 "file": f"bench/configs/{tiny.name}.json", "reduced": []})
+        bench["workloads"].append({"name": tiny.name, "config": tiny.name,
+                                   "traffic": tiny.name, "chips": tiny.chips,
+                                   "why": f"rehearsal of {real}"})
         for m in bench["end_to_end"] + bench["per_layer"]:
-            if "workloads" in m:
-                m["workloads"].append(name)
+            if real in m.get("workloads", ()):
+                m["workloads"].append(tiny.name)
     (dest / "BENCHMARK.json").write_text(json.dumps(bench))
     return dest
 

@@ -1,6 +1,6 @@
 """The control comes out as not correct: the plain reference computed in
-bfloat16, put in the program's place, fails at least one of each cell's
-limits.  On the CPU, at a size a test run holds: the real widths, depths
+bfloat16, put in the program's place, fails at least one of each training
+cell's limits.  On the CPU, at a size a test run holds: the real widths, depths
 and batch, the id space cut to a hundredth (the bfloat16 error that fails
 the gradient comes from summing a batch's occurrences of the hottest rows,
 so the batch stays whole)."""
@@ -15,7 +15,9 @@ import harness
 import zipf
 from conftest import ROOT
 
-CELLS = [w["name"] for w in json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
+#: The training cells: a runner of another kind keeps its control beside it.
+CELLS = [w["name"] for w in json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]
+         if harness.load_cell(w["name"]).traffic["kind"] == "train"]
 
 
 def _small(cfg: dict) -> dict:

@@ -1,4 +1,6 @@
 """Work counts of the per-layer metrics against hand arithmetic."""
+import pytest
+
 import harness
 
 LM = harness.BENCH / "layer_metrics"
@@ -34,8 +36,12 @@ def test_dcn_forward_flops():
 
 
 class _Red:
-    def __init__(self, ops, spans, busy=1.0, window=2.0):
+    """A reduction with the readings given: op seconds by name, spans as
+    (name, (span seconds, busy seconds)), seconds by scope, mean span ms."""
+
+    def __init__(self, ops, spans, busy=1.0, window=2.0, scopes=None, span_ms=None):
         self.ops, self.spans, self.busy_s, self.window_s = ops, spans, busy, window
+        self.scopes, self.ms = scopes or {}, span_ms or {}
 
     def ops_matching(self, pattern):
         import re
@@ -44,6 +50,15 @@ class _Red:
 
     def span_busy(self, name):
         return [s for n, s in self.spans if n == name]
+
+    def count(self, name):
+        return len(self.span_busy(name))
+
+    def scope_seconds(self, scope):
+        return self.scopes.get(scope, 0.0)
+
+    def span_ms(self, name):
+        return self.ms.get(name)
 
 
 class _Run:
@@ -68,10 +83,40 @@ def test_train_mfu_over_device_time():
     assert abs(m.read(run) - 100 * 10 * 3 * 326 / (0.5 * 2 * 1e6)) < 1e-9
 
 
-def test_readers_return_nothing_without_their_kernel():
-    for name in ("dequant_gather_roofline.train", "sparse_row_update_roofline"):
-        m = harness.load_module(LM / f"{name}.py")
-        red = _Red({"fusion.1": 1.0}, [("bench.train_step", (1, 1))])
-        assert m.read(_Run(red, {"lookups_per_step": 4, "chips": 1, "unique_ids": 3},
-                           _cfg(8))) is None
-        assert m.read(_Run(None, {}, _cfg(8))) is None
+def test_row_update_roofline_reads_its_scope():
+    m = harness.load_module(LM / "sparse_row_update_roofline.py")
+    red = _Red({"fusion.1": 9.0}, [], scopes={"lpt.row_update": 0.25, "lpt.dedup": 5.0})
+    run = _Run(red, {"unique_ids": 10, "chips": 1}, _cfg(8))
+    # 10 unique ids x 424 B = 4240 B at 1000 B/s = 4.24 s least, over 0.25 s.
+    assert m.bytes_per_unique(_cfg(8)) == 424
+    assert m.read(run) == pytest.approx(100 * 4.24 / 0.25)
+
+
+@pytest.mark.parametrize("name,scope", [("dedup_ms.train", "lpt.dedup"),
+                                        ("step_size_ms.train", "alpt.step_size")])
+def test_scope_ms_per_step(name, scope):
+    m = harness.load_module(LM / f"{name}.py")
+    red = _Red({}, [("bench.train_step", (1, 1))] * 4,
+               scopes={scope: 0.02, "lpt.row_update": 9.0})
+    # 20 ms under the scope over 4 benchmark steps.
+    assert m.read(_Run(red, {}, _cfg(8))) == pytest.approx(5.0)
+
+
+def test_host_step_ms_is_the_mean_train_step_span():
+    m = harness.load_module(LM / "host_step_ms.train.py")
+    red = _Red({}, [], span_ms={"train.step": 5.5, "train.feed": 0.8})
+    assert m.read(_Run(red, {}, _cfg(8))) == 5.5
+
+
+@pytest.mark.parametrize("name", ["dequant_gather_roofline.train", "sparse_row_update_roofline",
+                                  "dedup_ms.train", "step_size_ms.train",
+                                  "host_step_ms.train"])
+def test_readers_return_nothing_without_their_kernel(name):
+    """Without its kernel, scope or span in the trace, or without a trace,
+    a reader returns nothing (never 0)."""
+    m = harness.load_module(LM / f"{name}.py")
+    red = _Red({"fusion.1": 1.0}, [("bench.train_step", (1, 1))],
+               scopes={"jit(step_fn)": 1.0}, span_ms={"train.feed": 1.0})
+    assert m.read(_Run(red, {"lookups_per_step": 4, "chips": 1, "unique_ids": 3},
+                       _cfg(8))) is None
+    assert m.read(_Run(None, {}, _cfg(8))) is None

@@ -1,5 +1,6 @@
-"""The program's spans and scopes beside the trace reduction: by hand, on a
-recorded TPU trace, and in real profiler files."""
+"""The program's spans and scopes in the trace reduction, and the by-hand
+layout of ``program_trace``: by hand, on a recorded TPU trace, and in real
+profiler files."""
 import glob
 import json
 
@@ -18,7 +19,7 @@ def _program_trace():
     # the first (feed 2-8, dispatch 8-20) and the second (55-60).  Device ops
     # carry scope paths: a dedup sort 10-30, a fenced while 40-70 with a body
     # op 45-55 inside it, and an op with no scope 80-90.
-    return pt.ProgramTrace.from_json({
+    return tr.Trace.from_json({
         "devices": {"/device:TPU:0": [
             ["sort.1", 10, 30, "jit(step_fn)/lpt.dedup/sort"],
             ["while.1", 40, 70, "jit(step_fn)/alpt.step_size/while"],
@@ -31,33 +32,34 @@ def _program_trace():
 
 
 def test_program_spans_and_scopes_by_hand():
-    r = pt.reduce(_program_trace())
-    assert r.reduced.busy_s == pytest.approx(60e-9)  # 10-30, 40-70, 80-90
+    r = tr.reduce(_program_trace())
+    assert r.busy_s == pytest.approx(60e-9)  # 10-30, 40-70, 80-90
     # The while and its body op overlap: the scope counts 40-70 once.
     assert r.scope_seconds("alpt.step_size") == pytest.approx(30e-9)
     assert r.scope_seconds("lpt.dedup") == pytest.approx(20e-9)
     assert r.scope_seconds("lpt") == 0.0  # a whole component, not a prefix
     # Idle inside train.step: 2-10 in the first, none in 55-60 (the while runs).
-    assert r.idle_under("train.step") == pytest.approx(8e-9)
-    assert r.idle_under("train.feed") == pytest.approx(6e-9)
-    assert r.idle_under("bench.train_step") == pytest.approx(40e-9)  # all idle
-    assert r.idle_under("no.such.span") == 0.0
-    assert r.span_ms() == {"engine.wave": [1, pytest.approx(5e-6)],
-                           "train.dispatch": [1, pytest.approx(12e-6)],
-                           "train.feed": [1, pytest.approx(6e-6)],
-                           "train.step": [2, pytest.approx(11.5e-6)]}
-    s = r.summary(steps=2)
+    assert pt.idle_under(r, "train.step") == pytest.approx(8e-9)
+    assert pt.idle_under(r, "train.feed") == pytest.approx(6e-9)
+    assert pt.idle_under(r, "bench.train_step") == pytest.approx(40e-9)  # all idle
+    assert pt.idle_under(r, "no.such.span") == 0.0
+    assert pt.span_table(r) == {"engine.wave": [1, pytest.approx(5e-6)],
+                                "train.dispatch": [1, pytest.approx(12e-6)],
+                                "train.feed": [1, pytest.approx(6e-6)],
+                                "train.step": [2, pytest.approx(11.5e-6)]}
+    s = pt.summary(r, steps=2)
     assert s["host_step_ms"] == pytest.approx(11.5e-6)
     assert s["host_step_idle"] == pytest.approx(8.0)
     assert s["dedup_ms"] == pytest.approx(1e-5) and s["step_size_ms"] == pytest.approx(1.5e-5)
+    assert s["row_update_ms"] is None
     assert s["device_ops"][0] == ["while.1", pytest.approx(30e-9),
                                   "jit(step_fn)/alpt.step_size/while"]
     assert s["device_ops"][-1] == ["fusion.3", pytest.approx(10e-9), None]
 
 
 def test_idle_gaps_go_to_the_innermost_span_of_either_kind():
-    r = pt.reduce(_program_trace())
-    gaps = sorted((round(s * 1e9), n) for n, s in r.reduced.gaps)
+    r = tr.reduce(_program_trace())
+    gaps = sorted((round(s * 1e9), n) for n, s in r.gaps)
     # 0-10 (midpoint 5, in train.feed inside train.step inside the
     # benchmark's step), 30-40 and 70-80 (the benchmark's steps alone),
     # 90-100 (the benchmark's second step).
@@ -66,12 +68,12 @@ def test_idle_gaps_go_to_the_innermost_span_of_either_kind():
 
 
 def test_the_benchmarks_reduction_reads_as_without_the_program():
-    """The program's spans and scopes change no number that ``trace_reduce``
-    gives, only the holders of the idle gaps."""
+    """The program's spans and the ops' scopes change no number that
+    ``trace_reduce`` gave without them, only the holders of the idle gaps."""
     t = _program_trace()
-    alone = tr.reduce(tr.Trace(t.trace.devices, [s for s in t.trace.spans
-                                                 if s[0].startswith(tr.SPAN_PREFIX)]))
-    red = pt.reduce(t).reduced
+    alone = tr.reduce(tr.Trace({k: [e[:3] for e in v] for k, v in t.devices.items()},
+                               [s for s in t.spans if s[0].startswith(tr.SPAN_PREFIX)]))
+    red = tr.reduce(t)
     assert (red.busy_s, red.window_s, red.ops, red.op_counts, red.merged) == (
         alone.busy_s, alone.window_s, alone.ops, alone.op_counts, alone.merged)
     assert red.span_busy("bench.train_step") == alone.span_busy("bench.train_step")
@@ -80,22 +82,22 @@ def test_the_benchmarks_reduction_reads_as_without_the_program():
 
 def test_a_trace_without_program_spans_or_scopes_reads_nothing_of_them():
     t = _program_trace()
-    t.scopes = {k: [] for k in t.scopes}
-    t.trace.spans = [s for s in t.trace.spans if s[0].startswith(tr.SPAN_PREFIX)]
-    s = pt.reduce(t).summary(steps=2)
-    assert [s[k] for k in ("host_step_ms", "host_step_idle", "dedup_ms", "step_size_ms")] == [
-        None] * 4
+    t.devices = {k: [e[:3] for e in v] for k, v in t.devices.items()}
+    t.spans = [s for s in t.spans if s[0].startswith(tr.SPAN_PREFIX)]
+    s = pt.summary(tr.reduce(t), steps=2)
+    assert [s[k] for k in ("host_step_ms", "host_step_idle", "dedup_ms", "step_size_ms",
+                           "row_update_ms")] == [None] * 5
     assert s["spans"] == {}
 
 
 def test_recorded_tpu_trace():
     with open(RECORDED) as f:
         rec = json.load(f)
-    r = pt.reduce(pt.ProgramTrace.from_json(rec["trace"]))
+    r = tr.reduce(tr.Trace.from_json(rec["trace"]))
     want = rec["expect"]
-    assert r.reduced.window_s == pytest.approx(want["window_s"], rel=1e-9)
-    assert r.reduced.busy_s == pytest.approx(want["busy_s"], rel=1e-9)
-    s = r.summary(rec["counts"]["steps"])
+    assert r.window_s == pytest.approx(want["window_s"], rel=1e-9)
+    assert r.busy_s == pytest.approx(want["busy_s"], rel=1e-9)
+    s = pt.summary(r, rec["counts"]["steps"])
     assert s["device_ops"][0][:2] == [want["top_op"], pytest.approx(want["top_op_s"], rel=1e-9)]
     for key, value in rec["expect_program"].items():
         assert s[key] == pytest.approx(value, rel=1e-9), key
@@ -106,11 +108,10 @@ def test_host_step_is_the_hosts_work_not_the_devices_step():
     the dispatch only; the wait for the previous step follows it."""
     with open(RECORDED) as f:
         rec = json.load(f)
-    r = pt.reduce(pt.ProgramTrace.from_json(rec["trace"]))
-    spans = r.span_ms()
-    work = spans["train.feed"][1] + spans["train.dispatch"][1]
-    assert work <= spans["train.step"][1] < work + 0.05
-    assert spans["train.step"][1] < 0.1 * 1e3 * r.reduced.busy_s / rec["counts"]["steps"]
+    r = tr.reduce(tr.Trace.from_json(rec["trace"]))
+    work = r.span_ms("train.feed") + r.span_ms("train.dispatch")
+    assert work <= r.span_ms("train.step") < work + 0.05
+    assert r.span_ms("train.step") < 0.1 * 1e3 * r.busy_s / rec["counts"]["steps"]
 
 
 def test_reads_program_spans_from_a_profiler_file(tmp_path):
@@ -124,9 +125,8 @@ def test_reads_program_spans_from_a_profiler_file(tmp_path):
                 pass
     jax.profiler.stop_trace()
     path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
-    assert sorted(s[0] for s in pt.read_xplane(path).trace.spans) == [
+    assert sorted(s[0] for s in tr.read_xplane(path).spans) == [
         "bench.window", "ckpt.save", "engine.wave", "storage.writeback", "train.step"]
-    assert [s[0] for s in tr.read_xplane(path).spans] == ["bench.window"]
 
 
 def test_reads_scopes_from_the_profiles_hlo(tmp_path):
@@ -146,9 +146,9 @@ def test_reads_scopes_from_the_profiles_hlo(tmp_path):
     jax.profiler.stop_trace()
     path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
     # The metadata plane may also hold modules of earlier tests' programs.
-    paths = [p for m, names in pt.hlo_scopes(path).items() if m.startswith("jit_step_fn")
+    paths = [p for m, names in tr.hlo_scopes(path).items() if m.startswith("jit_step_fn")
              for p in names.values()]
-    assert any(pt.in_scope(p, "lpt.dedup") and "sort" in p for p in paths)
+    assert any(tr.in_scope(p, "lpt.dedup") and "sort" in p for p in paths)
     assert "jit(step_fn)/dot_general" in paths
 
 
@@ -157,11 +157,11 @@ def test_an_op_takes_the_scope_of_the_module_running_it():
               "jit_other(3)": {"fusion.1": "jit(other)/add"}}
     modules = [(0.0, 10.0, "jit_step_fn(7)"), (20.0, 30.0, "jit_other(3)")]
     starts = [m[0] for m in modules]
-    assert pt._scope_of(scopes, modules, starts, "fusion.1", 5.0) == "jit(step_fn)/lpt.dedup/sort"
-    assert pt._scope_of(scopes, modules, starts, "fusion.1", 25.0) == "jit(other)/add"
-    assert pt._scope_of(scopes, modules, starts, "fusion.1", 15.0) is None  # between modules
-    assert pt._scope_of(scopes, modules, starts, "fusion.9", 5.0) is None
-    assert pt._scope_of(scopes, [], [], "fusion.1", 5.0) is None
+    assert tr._scope_of(scopes, modules, starts, "fusion.1", 5.0) == "jit(step_fn)/lpt.dedup/sort"
+    assert tr._scope_of(scopes, modules, starts, "fusion.1", 25.0) == "jit(other)/add"
+    assert tr._scope_of(scopes, modules, starts, "fusion.1", 15.0) is None  # between modules
+    assert tr._scope_of(scopes, modules, starts, "fusion.9", 5.0) is None
+    assert tr._scope_of(scopes, [], [], "fusion.1", 5.0) is None
 
 
 def test_a_traced_cell_run_holds_the_trainers_spans(tiny_root):
@@ -179,9 +179,9 @@ def test_a_traced_cell_run_holds_the_trainers_spans(tiny_root):
                       peaks=harness.peaks_for("TPU v5 lite"))
     cell.runner().run(run)
     try:
-        assert pt.reduce_dir(run.trace_dir) is None
+        assert tr.reduce_dir(run.trace_dir) is None
         path = glob.glob(f"{run.trace_dir}/**/*.xplane.pb", recursive=True)[0]
-        spans = pt.read_xplane(path).trace.spans
+        spans = tr.read_xplane(path).spans
     finally:
         shutil.rmtree(run.trace_dir, ignore_errors=True)
     (lo, hi), = [(s, e) for n, s, e in spans if n == tr.WINDOW]

@@ -9,7 +9,7 @@ import sys
 
 import pytest
 
-from conftest import BENCH, ROOT, TINY_CELLS, run_cell
+from conftest import BENCH, ROOT, missing_rehearsals, run_cell, tiny_cells
 
 ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 
@@ -37,18 +37,20 @@ def test_refuses_in_a_checkout_of_the_benchmark_alone(tmp_path):
 
 def test_every_cell_loads_and_matches_the_program():
     import harness
-    import program
 
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     for w in bench["workloads"]:
         cell = harness.load_cell(w["name"])
         assert cell.end_to_end and cell.per_layer and set(cell.limits)
         assert "setup_s" in {m["name"] for m in cell.end_to_end}
-        program.trainer_config(cell.config)  # raises where the program differs
-        cell.runner()
+        cell.runner().check_program(cell.config)  # raises where the program differs
 
 
-@pytest.mark.parametrize("name", [c[0] for c in TINY_CELLS if c[3] == 1])
+def test_every_configuration_has_a_rehearsal_file():
+    assert missing_rehearsals() == [], "add bench/tests/rehearsal/<config>.json"
+
+
+@pytest.mark.parametrize("name", [c.name for c in tiny_cells() if c.chips == 1])
 @pytest.mark.parametrize("trace", [False, True])
 def test_tiny_cell_runs_and_is_correct(tiny_root, name, trace):
     line, run = run_cell(tiny_root, name, trace=trace)
@@ -58,7 +60,7 @@ def test_tiny_cell_runs_and_is_correct(tiny_root, name, trace):
     if not trace:
         assert set(line["metrics"]) == {m["name"] for m in run.cell.end_to_end}
         assert all(m["value"] > 0 for m in line["metrics"].values())
-    assert run.counts["fallbacks"] == 0
+    assert run.counts.get("fallbacks", 0) == 0
 
 
 DP_SCRIPT = """
